@@ -58,7 +58,7 @@ import subprocess
 import sys
 from pathlib import Path
 
-from benchmarks.common import emit
+from benchmarks.common import emit, refuse_cpu_children_on_tpu
 
 ROOT = Path(__file__).resolve().parent.parent
 
@@ -163,6 +163,7 @@ print("RESULT " + json.dumps(
 
 def run(n: int = 2048, k: int = 4, iters: int = 5,
         n_ba: int = 1024, floor: float = 1.3):
+    refuse_cpu_children_on_tpu("bench_async")
     env = dict(os.environ)
     env["PYTHONPATH"] = os.pathsep.join(
         [str(ROOT), str(ROOT / "src"), env.get("PYTHONPATH", "")])

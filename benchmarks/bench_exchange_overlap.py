@@ -28,7 +28,7 @@ import subprocess
 import sys
 from pathlib import Path
 
-from benchmarks.common import emit
+from benchmarks.common import emit, refuse_cpu_children_on_tpu
 
 ROOT = Path(__file__).resolve().parent.parent
 
@@ -96,6 +96,7 @@ print("RESULT " + json.dumps(
 
 
 def run(scale: int = 12, k: int = 2, steps: int = 24, iters: int = 9):
+    refuse_cpu_children_on_tpu("bench_exchange_overlap")
     env = dict(os.environ)
     env["PYTHONPATH"] = os.pathsep.join(
         [str(ROOT), str(ROOT / "src"), env.get("PYTHONPATH", "")])

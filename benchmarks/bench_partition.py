@@ -28,7 +28,7 @@ import sys
 import time
 from pathlib import Path
 
-from benchmarks.common import emit
+from benchmarks.common import emit, refuse_cpu_children_on_tpu
 from repro.core.partition import (greedy_partition, hash_edge_cut,
                                   partition_quality)
 from repro.core.partition_stream import hdrf_partition
@@ -164,6 +164,7 @@ def run_dist(scale: int = 10, k: int = 4, iters: int = 5):
     the wall-clock rows record what that buys (`gate=False` — simulated
     devices on shared CI hosts are scheduler-bimodal; the within-run
     comparison is the signal)."""
+    refuse_cpu_children_on_tpu("bench_partition.run_dist")
     env = dict(os.environ)
     env["PYTHONPATH"] = os.pathsep.join(
         [str(ROOT), str(ROOT / "src"), env.get("PYTHONPATH", "")])

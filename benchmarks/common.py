@@ -37,6 +37,19 @@ class TimedUs(float):
         return obj
 
 
+def refuse_cpu_children_on_tpu(bench: str) -> None:
+    """Subprocess benches time CPU-simulated meshes in child processes.  On
+    a TPU host the parent (which has already touched JAX) holds the chip,
+    and the children would quietly time the CPU instead — refuse."""
+    import jax
+    if jax.devices()[0].platform == "tpu":
+        raise RuntimeError(
+            f"{bench} times CPU-simulated multi-device child processes, but "
+            f"this process sees a TPU ({jax.devices()[0].device_kind}) and "
+            f"holds it; run this bench with JAX_PLATFORMS=cpu, which makes "
+            f"it a CPU smoke check, not a chip measurement")
+
+
 def time_fn(fn: Callable, *args, warmup: int = 2, iters: int = 5) -> TimedUs:
     """Median wall time per call in microseconds (blocking on outputs),
     with the max/median dispersion across the timed iterations attached

@@ -14,11 +14,14 @@ import json
 import platform
 import sys
 
+import jax
+
 from benchmarks import (bench_async, bench_exchange_overlap, bench_frontier,
                         bench_gas_vs_sc, bench_incremental, bench_memory,
                         bench_pagerank, bench_partition, bench_serving,
                         bench_traversal, bench_tuning, bench_vector_combine,
                         bench_weak, common)
+from repro.compile_cache import enable_compile_cache
 
 SUITES = {
     "pagerank": bench_pagerank.main,     # Table 5 / Fig. 8a-b
@@ -85,6 +88,7 @@ def main() -> None:
         except IndexError:
             sys.exit("--json needs an output path")
         del args[i:i + 2]
+    enable_compile_cache()
     wanted = args or list(SMOKE if smoke else SUITES)
     unknown = [n for n in wanted if n not in SUITES]
     if unknown:
@@ -96,10 +100,14 @@ def main() -> None:
         else:
             SUITES[name]()
     if json_path:
+        dev = jax.devices()[0]
         payload = {
             "mode": "smoke" if smoke else "full",
             "python": platform.python_version(),
             "machine": platform.machine(),
+            "platform": dev.platform,
+            "device_kind": dev.device_kind,
+            "device_count": jax.device_count(),
             "results": common.RESULTS,
         }
         with open(json_path, "w") as f:

@@ -44,7 +44,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.agent_graph import AgentGraph, split_edge_tiles
 from repro.core.engine import DevicePartition, EngineState, GREEngine
@@ -56,6 +56,7 @@ from repro.core.exchange import (AgentExchange, AsyncAgentExchange,
 from repro.core.plan import execute_plan, execute_superstep
 from repro.core.vertex_program import VertexProgram
 from repro.dist.sharding import shard_map
+from repro.kernels.segment_combine import build_block_table
 
 
 def _squeeze0(tree):
@@ -66,6 +67,12 @@ def _squeeze0(tree):
 
 def _unsqueeze0(tree):
     return jax.tree.map(lambda a: a[None] if hasattr(a, "ndim") else a, tree)
+
+
+def _block_tables(dst: np.ndarray, num_segments: int) -> np.ndarray:
+    """Per-shard Pallas block schedules of stacked dst-sorted columns
+    `[k, E]` -> `[k, 2, G]` (equal E gives equal G, so they stack)."""
+    return np.stack([build_block_table(row, num_segments) for row in dst])
 
 __all__ = ["DistGREEngine", "PipelineTiles", "PipelinedAgentExchange",
            "ShardTopology", "flush_combiners", "refresh_scatter_agents",
@@ -226,8 +233,9 @@ class DistGREEngine:
         """
         if self._auto_plan_pending:
             self._resolve_auto_plan(ag)
-        aux = {"out_degree": jnp.asarray(ag.out_degree),
-               "global_id": jnp.asarray(
+        put = self._put_rows
+        aux = {"out_degree": put(ag.out_degree),
+               "global_id": put(
                    ag.new2old.reshape(ag.k, ag.cap).astype(np.float32))}
         if self.exchange in ("pipelined", "async"):
             part = DevicePartition(
@@ -237,29 +245,40 @@ class DistGREEngine:
             tiles = self._pipeline_tiles(ag)
         else:
             part = DevicePartition(
-                src=jnp.asarray(ag.src), dst=jnp.asarray(ag.dst),
-                edge_mask=jnp.asarray(ag.edge_mask),
+                src=put(ag.src), dst=put(ag.dst),
+                edge_mask=put(ag.edge_mask),
                 num_masters=ag.cap, num_slots=ag.num_slots,
                 edges_sorted_by_dst=True,
-                edge_props={n: jnp.asarray(v)
-                            for n, v in ag.edge_props.items()},
+                edge_props={n: put(v) for n, v in ag.edge_props.items()},
                 aux=aux,
-                csr_indptr=jnp.asarray(ag.csr_indptr),
-                csr_eidx=jnp.asarray(ag.csr_eidx),
+                csr_indptr=put(ag.csr_indptr),
+                csr_eidx=put(ag.csr_eidx),
                 csr_max_deg=ag.csr_max_deg,
-                bucket_id=jnp.asarray(ag.bucket_id),
+                bucket_id=put(ag.bucket_id),
                 bucket_sizes=ag.bucket_sizes,
                 bucket_max_deg=ag.bucket_max_deg,
+                combine_table=put(_block_tables(ag.dst, ag.num_slots)),
             )
             tiles = None
         return ShardTopology(
             part=part,
-            comb_send_slot=jnp.asarray(ag.comb_send_slot),
-            comb_recv_master=jnp.asarray(ag.comb_recv_master),
-            scat_send_master=jnp.asarray(ag.scat_send_master),
-            scat_recv_slot=jnp.asarray(ag.scat_recv_slot),
+            comb_send_slot=put(ag.comb_send_slot),
+            comb_recv_master=put(ag.comb_recv_master),
+            scat_send_master=put(ag.scat_send_master),
+            scat_recv_slot=put(ag.scat_recv_slot),
             tiles=tiles,
         )
+
+    @property
+    def _row_sharding(self) -> NamedSharding:
+        """Row i of a stacked `[k, ...]` array lives on mesh device i."""
+        return NamedSharding(self.mesh, P(self.axes if len(self.axes) > 1
+                                          else self.axes[0]))
+
+    def _put_rows(self, stacked):
+        """Place a host-stacked `[k, ...]` array row-per-device, so no shard
+        is staged on the first device before shard_map splits it."""
+        return jax.device_put(np.asarray(stacked), self._row_sharding)
 
     def _pipeline_tiles(self, ag: AgentGraph) -> PipelineTiles:
         """Stacked remote/local edge tiles + compact-space exchange indices.
@@ -273,28 +292,32 @@ class DistGREEngine:
         """
         split = split_edge_tiles(ag)
         comb_base = ag.cap + ag.s_pad
+        put = self._put_rows
 
-        def tile_part(t):
+        def tile_part(t, num_segments):
+            # the tile's ⊕ runs over its compact segment space (see
+            # PipelinedAgentExchange.local_phase), so its Pallas block
+            # schedule is built over that space too
             return DevicePartition(
-                src=jnp.asarray(t.src), dst=jnp.asarray(t.dst),
-                edge_mask=jnp.asarray(t.mask),
+                src=put(t.src), dst=put(t.dst),
+                edge_mask=put(t.mask),
                 num_masters=ag.cap, num_slots=ag.num_slots,
                 edges_sorted_by_dst=True,
-                edge_props={n: jnp.asarray(v) for n, v in t.props.items()},
-                csr_indptr=jnp.asarray(t.csr_indptr),
-                csr_eidx=jnp.asarray(t.csr_eidx),
+                edge_props={n: put(v) for n, v in t.props.items()},
+                csr_indptr=put(t.csr_indptr),
+                csr_eidx=put(t.csr_eidx),
                 csr_max_deg=t.csr_max_deg,
-                bucket_id=jnp.asarray(t.bucket_id),
+                bucket_id=put(t.bucket_id),
                 bucket_sizes=t.bucket_sizes,
                 bucket_max_deg=t.bucket_max_deg,
+                combine_table=put(_block_tables(t.dst, num_segments)),
             )
 
         return PipelineTiles(
-            part_remote=tile_part(split.remote),
-            part_local=tile_part(split.local),
-            comb_send_compact=jnp.asarray(ag.comb_send_slot - comb_base),
-            comb_recv_master=jnp.asarray(
-                np.minimum(ag.comb_recv_master, ag.cap)),
+            part_remote=tile_part(split.remote, ag.c_pad + 1),
+            part_local=tile_part(split.local, ag.cap + 1),
+            comb_send_compact=put(ag.comb_send_slot - comb_base),
+            comb_recv_master=put(np.minimum(ag.comb_recv_master, ag.cap)),
             num_combiners=ag.c_pad,
         )
 
@@ -363,8 +386,9 @@ class DistGREEngine:
                 raise ValueError(f"expected {D} source entries")
             row = np.zeros(D, dtype=bool) if not seeded else np.array(seeded)
             lane_active = jnp.broadcast_to(jnp.asarray(row)[None, :], (k, D))
-        return EngineState(vd, sd, act, jnp.zeros((k,), jnp.int32),
-                           lane_active)
+        return jax.device_put(
+            EngineState(vd, sd, act, jnp.zeros((k,), jnp.int32),
+                        lane_active), self._row_sharding)
 
     # ------------------------------------------------------------ incremental
     def warm_start_state(self, ag: AgentGraph, prev_state: EngineState,
@@ -487,7 +511,7 @@ class DistGREEngine:
                 "exchange='agent' or 'pipelined' for serving.")
         if self._auto_plan_pending:
             self._resolve_auto_plan(ag)
-        spec_leading = P(self.axes if len(self.axes) > 1 else self.axes[0])
+        spec_leading = self._row_sharding.spec
 
         def tick_shard(topo_stack, state_stack):
             topo_l = _squeeze0(topo_stack)
@@ -511,7 +535,7 @@ class DistGREEngine:
         """Build the jitted distributed run function over the mesh."""
         if self._auto_plan_pending:
             self._resolve_auto_plan(ag)
-        spec_leading = P(self.axes if len(self.axes) > 1 else self.axes[0])
+        spec_leading = self._row_sharding.spec
         squeeze0, unsqueeze0 = _squeeze0, _unsqueeze0
 
         def glob_any(local):

@@ -88,6 +88,11 @@ class DevicePartition:
                                             metadata=dict(static=True))
     bucket_max_deg: tuple = dataclasses.field(default=(),
                                               metadata=dict(static=True))
+    # Ingress-time Pallas block schedule of the dst-sorted `dst` column over
+    # `num_slots` (kernels.segment_combine.build_block_table) — what the
+    # dense scan's `use_pallas` route visits.  None on partitions whose
+    # edges are not dst-sorted; that route then refuses to run.
+    combine_table: Optional[jnp.ndarray] = None   # [2, G] int32
 
     @staticmethod
     def from_graph(graph, pad_to: Optional[int] = None,
@@ -121,6 +126,7 @@ class DevicePartition:
         from repro.graph.structures import (DEFAULT_BUCKET_BOUNDS,
                                             csr_layout, degree_buckets,
                                             pad_edges, sort_edges_by_dst)
+        from repro.kernels.segment_combine import build_block_table
         source = graph if hasattr(graph, "chunks") else (
             graph.chunk_source(chunk_size) if chunk_size else None)
         if source is not None:
@@ -168,6 +174,8 @@ class DevicePartition:
         bucket_id, sizes, max_degs = degree_buckets(
             indptr, v + 1, bounds=tuple(bucket_bounds or
                                         DEFAULT_BUCKET_BOUNDS))
+        table = (jnp.asarray(build_block_table(pdst, v + 1))
+                 if sort_by_dst else None)
         return DevicePartition(
             src=jnp.asarray(psrc), dst=jnp.asarray(pdst),
             edge_mask=jnp.asarray(mask), num_masters=v, num_slots=v + 1,
@@ -178,7 +186,7 @@ class DevicePartition:
             csr_indptr=jnp.asarray(indptr), csr_eidx=jnp.asarray(eidx),
             csr_max_deg=max_deg,
             bucket_id=jnp.asarray(bucket_id), bucket_sizes=sizes,
-            bucket_max_deg=max_degs,
+            bucket_max_deg=max_degs, combine_table=table,
         )
 
     def apply_edge_delta(self, delta, bucket_bounds: Optional[tuple] = None,
@@ -212,6 +220,7 @@ class DevicePartition:
                                             degree_buckets, removal_selector,
                                             sort_edges_by_dst,
                                             validate_edge_delta)
+        from repro.kernels.segment_combine import build_block_table
         assert self.src is not None, \
             "tile-only partition carries no edge columns to mutate"
         n, slots = self.num_masters, self.num_slots
@@ -290,7 +299,9 @@ class DevicePartition:
             csr_indptr=jnp.asarray(indptr), csr_eidx=jnp.asarray(eidx),
             csr_max_deg=max_deg,
             bucket_id=jnp.asarray(bucket_id), bucket_sizes=sizes,
-            bucket_max_deg=max_degs)
+            bucket_max_deg=max_degs,
+            combine_table=(jnp.asarray(build_block_table(pdst, slots))
+                           if self.edges_sorted_by_dst else None))
         report = DeltaReport(added_src=delta.add_src.copy(),
                              added_dst=delta.add_dst.copy(),
                              removed_src=removed_src,
@@ -712,10 +723,33 @@ class GREEngine:
             live = live.reshape(live.shape + (1,) * (msgs.ndim - live.ndim))
             msgs = jnp.where(live, msgs.astype(p.msg_dtype),
                              p.monoid.identity)
+        nseg = num_segments or part.num_slots
+        table = None
+        if self.use_pallas:
+            table = self._combine_table(part, nseg)
         return segment_combine(
-            msgs, part.dst, num_segments or part.num_slots, p.monoid,
+            msgs, part.dst, nseg, p.monoid,
             indices_are_sorted=part.edges_sorted_by_dst,
-            use_pallas=self.use_pallas)
+            use_pallas=self.use_pallas, table=table)
+
+    @staticmethod
+    def _combine_table(part: DevicePartition, num_segments: int):
+        """The dense scan's Pallas block schedule: the partition's
+        ingress-time table, checked against this call's segment space.  A
+        partition whose edges are not dst-sorted (or that carries no table)
+        gets None, which the kernel wrapper accepts only for a concrete
+        `dst` column — never a quiet reference fallback."""
+        if part.combine_table is None or not part.edges_sorted_by_dst:
+            return None
+        from repro.kernels.segment_combine import table_length
+        want = table_length(part.dst.shape[-1], num_segments)
+        if part.combine_table.shape[-1] != want:
+            raise ValueError(
+                f"partition's block table has {part.combine_table.shape[-1]}"
+                f" visits; a {num_segments}-segment combine over "
+                f"{part.dst.shape[-1]} edges needs {want} — it was built "
+                f"for another segment space")
+        return part.combine_table
 
     # ------------------------------------------------------------------ apply
     def apply(self, part: DevicePartition, state: EngineState,

@@ -167,7 +167,10 @@ def refresh_scatter_agents(topo: ShardTopology, scatter_data: jnp.ndarray,
     acts = jnp.take(active, topo.scat_send_master, axis=0)         # [k, x]
     rec_a = jax.lax.all_to_all(acts, axes, split_axis=0, concat_axis=0,
                                tiled=True)
-    act = active.at[slots].set(rec_a.reshape(-1), mode="drop")
+    # scattered as int32: the TPU compiler lowers a sub-32-bit scatter
+    # through a sort, ~8 s of compile per scatter at 10^5 indices
+    act = active.astype(jnp.int32).at[slots].set(
+        rec_a.reshape(-1).astype(jnp.int32), mode="drop") > 0
     return sd, act
 
 

@@ -5,8 +5,9 @@ The dense scatter path scans EVERY edge each superstep and masks by
 frontier wastes 99% of its gather bandwidth (the inactive-vertex overhead
 that dominates vertex-centric runtimes).  This module compacts instead:
 
-  1. `jnp.nonzero(active, size=cap)` extracts at most `cap` active slots
-     (fixed capacity keeps the shape static for jit);
+  1. `compact_indices(active, cap)` (`jnp.nonzero` with a static size)
+     extracts at most `cap` active slots (fixed capacity keeps the shape
+     static for jit);
   2. CSR `indptr` (built at ingress, `graph.structures.csr_layout`) gives
      each frontier slot's out-edge range; ranges are gathered into a padded
      edge tile via the src-sorted position index `csr_eidx` — destinations
@@ -159,6 +160,24 @@ def gather_frontier_edge_tile(part: "DevicePartition", frontier: jnp.ndarray,
     return part.csr_eidx[pos], valid
 
 
+def compact_indices(mask: jnp.ndarray, size: int,
+                    fill_value: int) -> jnp.ndarray:
+    """`jnp.nonzero(mask, size=size, fill_value=fill_value)[0]`, built from
+    a two-level prefix sum (1024-wide rows, then the row totals) and one
+    int32 scatter.  Same result; the TPU compiler takes 7–28 s for the flat
+    prefix sum `jnp.nonzero` lowers to at 10^5–10^6 slots, and under 2 s
+    for this one."""
+    n, width = mask.shape[0], 1024
+    rows = -(-n // width)
+    m = jnp.pad(mask.astype(jnp.int32), (0, rows * width - n))
+    inner = jnp.cumsum(m.reshape(rows, width), axis=1)
+    offset = jnp.cumsum(inner[:, -1]) - inner[:, -1]
+    pos = (inner + offset[:, None]).reshape(-1)[:n] - 1
+    return jnp.full((size,), fill_value, jnp.int32).at[
+        jnp.where(mask, pos, size)].set(jnp.arange(n, dtype=jnp.int32),
+                                         mode="drop")
+
+
 def _tile_combine(program: "VertexProgram", msgs: jnp.ndarray,
                   dst: jnp.ndarray, num_segments: int,
                   kernel: KernelPlan = XLA_KERNEL) -> jnp.ndarray:
@@ -200,7 +219,7 @@ def compact_scatter_combine(program: "VertexProgram", part: "DevicePartition",
     if max_deg is None:
         max_deg = part.csr_max_deg
     mask = state.active_scatter if frontier_mask is None else frontier_mask
-    (frontier,) = jnp.nonzero(mask, size=cap, fill_value=part.num_slots)
+    frontier = compact_indices(mask, cap, part.num_slots)
     eid, valid = gather_frontier_edge_tile(part, frontier, cap, max_deg)
     # invalid lanes carry identity msgs AND the out-of-range dst sentinel:
     # XLA scatter-reduces drop them, and the Pallas dynamic pruning pass
@@ -296,15 +315,14 @@ def bucketed_tile_occupancy(part: "DevicePartition", active: jnp.ndarray,
         if cap_b <= 0 or max_deg_b <= 0:
             continue
         mask_b = active & (part.bucket_id == b)
-        (frontier,) = jnp.nonzero(mask_b, size=cap_b,
-                                  fill_value=part.num_slots)
+        frontier = compact_indices(mask_b, cap_b, part.num_slots)
         eid, valid = gather_frontier_edge_tile(part, frontier, cap_b,
                                                max_deg_b)
         dst = jnp.sort(jnp.where(valid, part.dst[eid], nseg).reshape(-1))
         table = dynamic_block_table(dst, nseg, block_e, block_v)
-        n_e = table.shape[1]
-        visited += int(jnp.sum(table < n_e))
-        total += table.shape[0] * n_e
+        n_e = -(-dst.shape[0] // block_e)
+        visited += int(jnp.sum(table[1] < n_e))
+        total += -(-nseg // block_v) * n_e
     return visited, total
 
 

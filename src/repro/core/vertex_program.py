@@ -89,19 +89,19 @@ MONOIDS: Dict[str, Monoid] = {
 
 def segment_combine(msgs: jnp.ndarray, dst: jnp.ndarray, num_segments: int,
                     monoid: Monoid, indices_are_sorted: bool = False,
-                    use_pallas: bool = False, interpret: bool = True
-                    ) -> jnp.ndarray:
+                    use_pallas: bool = False, table=None) -> jnp.ndarray:
     """One-sided combine of active messages at their destinations.
 
     This is the Scatter-Combine hot path.  The XLA path lowers to a fused
     scatter-reduce; the Pallas path (TPU target) tiles dst-sorted edges into
     VMEM blocks and turns the irregular reduction into block-local one-hot
-    MXU matmuls (sum) or masked VPU reductions (min/max).
+    MXU matmuls (sum) or masked VPU reductions (min/max), visiting the
+    blocks that `table` (the ingress-time block schedule of `dst`) lists.
     """
     if use_pallas:
         from repro.kernels import ops as kernel_ops
         return kernel_ops.segment_combine(msgs, dst, num_segments,
-                                          monoid.name, interpret=interpret)
+                                          monoid.name, table=table)
     return monoid.segment_reduce(msgs, dst, num_segments, indices_are_sorted)
 
 

@@ -2,9 +2,8 @@
 
 Three groups:
 
-  * `shard_map` — version shim: jax >= 0.5 exposes `jax.shard_map`
-    (`check_vma`); 0.4.x keeps it in `jax.experimental.shard_map`
-    (`check_rep`).  Every shard_map in this repo goes through here.
+  * `shard_map` — `jax.shard_map` with the replication check off; every
+    shard_map in this repo goes through here.
   * spec trees — `lm_param_specs` / `opt_specs` / ... return PartitionSpec
     pytrees that mirror the corresponding parameter pytrees (dense parts
     tensor-parallel over `tp`, embeddings row-sharded, MoE expert-sharded).
@@ -21,16 +20,11 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from repro.configs.base import LMConfig, RecSysConfig
 
 
-# ------------------------------------------------------------- version shim
+# ----------------------------------------------------------------- shard_map
 def shard_map(f, mesh: Mesh, in_specs, out_specs, check: bool = False):
-    """Portable shard_map: prefers `jax.shard_map` (jax >= 0.5), falls back
-    to `jax.experimental.shard_map.shard_map` (0.4.x)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check)
+    """`jax.shard_map` with `check_vma=check` (off by default)."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check)
 
 
 # ----------------------------------------------------------------- utilities

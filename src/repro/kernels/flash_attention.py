@@ -19,6 +19,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import on_backend
+
 NEG_INF = -1e30
 
 
@@ -65,14 +67,13 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
         o_ref[0] = (acc_scr[...] / l[:, None]).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("causal", "block_q", "block_k",
-                                             "interpret"))
+@functools.partial(jax.jit, static_argnames=("causal", "block_q", "block_k"))
 def flash_attention_pallas(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                            causal: bool = True, block_q: int = 128,
-                           block_k: int = 512, interpret: bool = True
-                           ) -> jnp.ndarray:
+                           block_k: int = 512) -> jnp.ndarray:
     """q [BH, Sq, D], k/v [BH, Sk, D] (heads flattened into batch; GQA is
-    handled by the ops.py wrapper which expands kv heads)."""
+    handled by the ops.py wrapper which expands kv heads).  Compiled on a
+    TPU, interpreted on CPU (`repro.kernels.on_backend`)."""
     bh, sq, d = q.shape
     sk = k.shape[1]
     block_q = min(block_q, sq)
@@ -80,7 +81,14 @@ def flash_attention_pallas(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     assert sq % block_q == 0 and sk % block_k == 0, (sq, block_q, sk, block_k)
     n_q, n_k = sq // block_q, sk // block_k
     scale = 1.0 / np.sqrt(d)
+    return on_backend(functools.partial(
+        _call, scale=scale, causal=causal, block_q=block_q, block_k=block_k,
+        n_q=n_q, n_k=n_k), q, k, v)
 
+
+def _call(q, k, v, *, scale: float, causal: bool, block_q: int,
+          block_k: int, n_q: int, n_k: int, interpret: bool):
+    bh, sq, d = q.shape
     return pl.pallas_call(
         functools.partial(_kernel, scale=scale, causal=causal,
                           block_q=block_q, block_k=block_k, n_k=n_k),

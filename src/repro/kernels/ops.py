@@ -1,51 +1,58 @@
-"""jit'd wrappers dispatching to the Pallas kernels (TPU) with automatic
-fallback to the jnp reference path (useful on CPU where only interpret mode
-exists).  These are the call sites models use via `use_pallas` flags.
+"""jit'd wrappers dispatching to the Pallas kernels — Mosaic-compiled on a
+TPU, interpreted on CPU (`repro.kernels.on_backend`).  These are the call
+sites models use via `use_pallas` flags.
 """
 from __future__ import annotations
 
 from typing import Optional
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels import ref as kref
 from repro.kernels.flash_attention import flash_attention_pallas
-from repro.kernels.segment_combine import build_block_table, segment_combine_pallas
+from repro.kernels.segment_combine import (BLOCK_E, BLOCK_V,
+                                           build_block_table,
+                                           segment_combine_pallas)
 
 
 def segment_combine(msgs: jnp.ndarray, dst: jnp.ndarray, num_segments: int,
                     op: str = "sum", table: Optional[jnp.ndarray] = None,
-                    interpret: bool = True, block_e: int = 256,
-                    block_v: int = 256) -> jnp.ndarray:
+                    block_e: int = BLOCK_E,
+                    block_v: int = BLOCK_V) -> jnp.ndarray:
     """Scatter-combine ⊕ along dst-sorted edges.
 
-    `table` is the ingress-time block index (see
-    segment_combine.build_block_table); when absent (or ids are traced) we
-    fall back to the jnp oracle — the Pallas path needs static topology,
-    which graph workloads have (topology is built once at ingress).
+    `table` is the ingress-time block schedule of the (dst-sorted) `dst`
+    column (segment_combine.build_block_table).  Without one, `dst` must be
+    concrete: it is sorted on the host and its schedule built here — graph
+    workloads have static topology, built once at ingress.  A traced `dst`
+    without a table is an error: the kernel cannot schedule blocks it
+    cannot see.
     """
     squeeze = msgs.ndim == 1
     m2 = msgs[:, None] if squeeze else msgs
     if table is None:
-        try:
-            dst_np = np.asarray(dst)
-        except Exception:
-            out = kref.segment_combine_ref(m2, dst, num_segments, op)
-            return out[:, 0] if squeeze else out
+        if isinstance(dst, jax.core.Tracer):
+            raise ValueError(
+                "Pallas segment_combine got a traced dst column and no "
+                "block table: build the schedule at ingress "
+                "(segment_combine.build_block_table) and pass `table=`")
+        dst_np = np.asarray(dst)
+        order = np.argsort(dst_np, kind="stable")
+        dst_np = dst_np[order]
+        m2 = m2[jnp.asarray(order)]
+        dst = jnp.asarray(dst_np)
         table = jnp.asarray(build_block_table(dst_np, num_segments,
                                               block_e, block_v))
-    out = segment_combine_pallas(m2.astype(jnp.float32), dst, table,
-                                 num_segments, op, block_e=block_e,
-                                 block_v=block_v, interpret=interpret)
+    out = segment_combine_pallas(m2, dst, table, num_segments, op,
+                                 block_e=block_e, block_v=block_v)
     out = out.astype(msgs.dtype)
     return out[:, 0] if squeeze else out
 
 
 def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                     causal: bool = True, block_q: int = 128,
-                    block_k: int = 512, interpret: bool = True
-                    ) -> jnp.ndarray:
+                    block_k: int = 512) -> jnp.ndarray:
     """GQA wrapper: q [B, Sq, Kv, G, H], k/v [B, Sk, Kv, H] — expands kv
     heads across the group dim and flattens (B, Kv, G) into the kernel's
     batch axis."""
@@ -57,18 +64,17 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     vf = jnp.broadcast_to(v.transpose(0, 2, 1, 3)[:, :, None],
                           (B, Kv, G, Sk, H)).reshape(B * Kv * G, Sk, H)
     o = flash_attention_pallas(qf, kf, vf, causal=causal, block_q=block_q,
-                               block_k=block_k, interpret=interpret)
+                               block_k=block_k)
     return o.reshape(B, Kv, G, Sq, H).transpose(0, 3, 1, 2, 4)
 
 
 def embedding_bag(table: jnp.ndarray, ids: jnp.ndarray, bag_ids: jnp.ndarray,
-                  num_bags: int, weights=None, seg_table=None,
-                  interpret: bool = True) -> jnp.ndarray:
+                  num_bags: int, weights=None,
+                  seg_table=None) -> jnp.ndarray:
     """EmbeddingBag = XLA gather (vocab-scale tables stay in HBM; TPU has no
     VMEM-resident gather for 10⁷-row tables) + Pallas segment-combine for the
     bag reduction (the hot ⊕)."""
     rows = jnp.take(table, ids, axis=0)
     if weights is not None:
         rows = rows * weights[:, None]
-    return segment_combine(rows, bag_ids, num_bags, "sum", table=seg_table,
-                           interpret=interpret)
+    return segment_combine(rows, bag_ids, num_bags, "sum", table=seg_table)

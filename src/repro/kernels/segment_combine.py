@@ -2,39 +2,55 @@
 
 TPU adaptation of the paper's active-message combine: instead of per-message
 atomic updates behind vLock (CPU), the irregular scatter becomes **block-local
-one-hot matmuls on the MXU** over dst-sorted edges:
+one-hot reductions** over dst-sorted edges:
 
   * edges are sorted by destination (done once at graph ingress, like the
     paper's CSR build, §6.1.1);
-  * the grid is (dst-row blocks × edge blocks); an SMEM prefetch table maps
-    each dst block to the edge blocks whose dst range intersects it, so empty
-    intersections are never visited (the CSR row-index analogue);
-  * each visit computes onehotᵀ @ msgs (sum ⊕, MXU-aligned [BE, BV] × [BE, D])
-    or a masked VPU reduction (min/max ⊕) and accumulates into the VMEM
-    output block.
+  * a BLOCK SCHEDULE lists the (dst block, edge block) pairs whose ranges
+    intersect, ordered by dst block — the CSR row-index analogue.  The grid
+    is one step per scheduled visit, so the work is proportional to the
+    visited pairs (≤ n_edge_blocks + n_dst_blocks for sorted edges), never
+    to n_dst_blocks × the widest row;
+  * each visit builds the `[BV, BE]` one-hot of its edge block against its
+    dst block and reduces it: `msgs @ onehotᵀ` on the MXU for sum ⊕, a
+    masked lane reduction on the VPU for min/max ⊕ (one payload lane at a
+    time).  Consecutive visits of one dst block accumulate into the same
+    VMEM output block, which is written back when the dst block changes.
 
-THREE block tables drive the same kernel (see docs/kernels.md):
+Edges run along the 128-wide lane axis: messages are carried transposed as
+`[D, E]` and destinations as `[1, E]`, so a scalar payload (D=1) is one
+dense lane row instead of an `[E, 1]` column padded 128-fold in HBM.
 
-  build_block_table    — host-side ingress pruning over the STATIC dst-sorted
-                         edge columns (the dense-path table);
-  dynamic_block_table  — the same pruning computed IN-GRAPH each superstep
-                         from a data-dependent (gathered, then dst-sorted)
-                         tile: per-edge-block dst min/max via blocked
-                         reductions, then the sentinel-padded intersection
-                         table.  This is the default for the frontier-
-                         compacted tile combine;
-  full_block_table     — the degenerate every-pair fallback, kept only for
+THREE functions produce the same `[2, G]` int32 schedule (row 0: dst block,
+row 1: edge block; see docs/kernels.md):
+
+  build_block_table    — host-side, at ingress, over a STATIC dst-sorted
+                         edge column (the dense-path schedule a partition
+                         carries as `DevicePartition.combine_table`);
+  dynamic_block_table  — the same construction IN-GRAPH each superstep for
+                         a data-dependent (gathered, then dst-sorted) tile —
+                         the default for the frontier-compacted combine;
+  full_block_table     — every (dst block, edge block) pair, kept only for
                          `dynamic=False` (the documented escape hatch when
                          the pruning pass is disabled).
 
-All three speak the same sentinel semantics: a table row is padded with
-`n_edge_blocks`, which indexes one appended all-identity dummy edge block;
-`@pl.when(eb < n_edge_blocks)` skips the visit entirely, so padded entries
-cost a (cache-resident) dummy block fetch and no compute.
+Every dst block appears at least once (a visit with edge block
+`n_edge_blocks` — the skip sentinel — initializes an untouched block to the
+identity), and trailing padding visits repeat the last dst block with the
+sentinel, so a schedule can be padded to a static length.
 
-VMEM working set per step: BE·D (messages) + BE (ids) + BV·D (out block).
-Defaults BE=256, BV=256, D ≤ 512 keep this well under 16 MB VMEM and the
-matmul dims multiples of the 128-lane MXU tiles.
+The schedule is scalar-prefetched into SMEM (1 MiB on v5e); schedules longer
+than `MAX_VISITS` run as a sequence of kernel calls over consecutive slices,
+each aliasing the previous call's output, and a dst block cut by a slice
+boundary resumes from that output.
+
+VMEM per grid step (defaults BE=1024, BV=256, f32): the double-buffered
+blocks `2·(8·BE + D₈·BE + 2·D₈·BV)` words (row counts pad to 8 sublanes:
+D₈ = D rounded up to 8; the output block and the resumed-output input)
+plus the `[BV, BE]` one-hot and its masked copy, 2·BV·BE words — about
+2.2 MiB at D=1 and 2.8 MiB at D=64, inside the 16 MiB default scoped
+VMEM.  The min/max body reduces one payload lane at a time, so no
+temporary grows with D.
 """
 from __future__ import annotations
 
@@ -46,6 +62,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import on_backend
+
 _OP_IDENTITY = {"sum": 0.0, "min": jnp.inf, "max": -jnp.inf}
 
 # Out-of-range destination sentinel: padded edges (and invalid tile lanes)
@@ -53,148 +71,166 @@ _OP_IDENTITY = {"sum": 0.0, "min": jnp.inf, "max": -jnp.inf}
 # and the in-kernel one-hot drop them.
 _DST_SENTINEL = np.int32(2**31 - 1)
 
+BLOCK_E = 1024
+BLOCK_V = 256
+# Visits per kernel call: the [3, MAX_VISITS] int32 schedule slice (dst
+# block, edge block, init mode) takes 384 KiB of the 1 MiB SMEM.
+MAX_VISITS = 32768
 
-def _kernel(table_ref, dst_ref, msgs_ref, out_ref, *, op: str, block_v: int,
-            n_edge_blocks: int):
-    iv = pl.program_id(0)
-    jj = pl.program_id(1)
 
-    @pl.when(jj == 0)
+def _kernel(sched_ref, dst_ref, msgs_ref, prev_ref, out_ref, *, op: str,
+            block_v: int, n_edge_blocks: int):
+    g = pl.program_id(0)
+    vb = sched_ref[0, g]
+    eb = sched_ref[1, g]
+    mode = sched_ref[2, g]   # 1: first visit of vb, 2: resume, 0: continue
+
+    @pl.when(mode == 1)
     def _init():
-        out_ref[...] = jnp.full_like(out_ref, _OP_IDENTITY[op])
+        out_ref[...] = jnp.full(out_ref.shape, _OP_IDENTITY[op],
+                                out_ref.dtype)
 
-    eb = table_ref[iv, jj]  # real edge-block id or n_edge_blocks (padding)
+    @pl.when(mode == 2)
+    def _resume():
+        out_ref[...] = prev_ref[...]
 
     @pl.when(eb < n_edge_blocks)
     def _accumulate():
-        v0 = iv * block_v
-        dst = dst_ref[...]                                  # [BE]
-        msgs = msgs_ref[...]                                # [BE, D]
-        local = dst - v0
-        onehot = (local[:, None] == jax.lax.broadcasted_iota(
-            jnp.int32, (dst.shape[0], block_v), 1))         # [BE, BV]
+        dst = dst_ref[...]                                   # [1, BE]
+        rows = jax.lax.broadcasted_iota(
+            jnp.int32, (block_v, dst.shape[1]), 0) + vb * block_v
+        hit = rows == dst                                    # [BV, BE]
         if op == "sum":
-            acc = jax.lax.dot_general(
-                onehot.astype(msgs.dtype), msgs,
-                (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)          # [BV, D] on MXU
-            out_ref[...] += acc.astype(out_ref.dtype)
-        else:
-            ident = _OP_IDENTITY[op]
-            expanded = jnp.where(onehot[:, :, None], msgs[:, None, :], ident)
-            red = expanded.min(0) if op == "min" else expanded.max(0)
-            cur = out_ref[...]
-            out_ref[...] = (jnp.minimum(cur, red) if op == "min"
-                            else jnp.maximum(cur, red))
+            out_ref[...] += jax.lax.dot_general(
+                msgs_ref[...], hit.astype(jnp.float32),
+                (((1,), (1,)), ((), ())),
+                precision=jax.lax.Precision.HIGHEST,
+                preferred_element_type=jnp.float32)         # [D, BV] on MXU
+            return
+        reduce = jnp.min if op == "min" else jnp.max
+        fold = jnp.minimum if op == "min" else jnp.maximum
+
+        def lane(d, carry):
+            msg = msgs_ref[pl.ds(d, 1), :]                   # [1, BE]
+            col = reduce(jnp.where(hit, msg, _OP_IDENTITY[op]), axis=1,
+                         keepdims=True)                      # [BV, 1]
+            row = jnp.transpose(jnp.broadcast_to(col, (block_v, 128)))[:1]
+            out_ref[pl.ds(d, 1), :] = fold(out_ref[pl.ds(d, 1), :], row)
+            return carry
+
+        jax.lax.fori_loop(0, msgs_ref.shape[0], lane, 0)
+
+
+def _schedule(xp, dst_sorted, num_segments: int, block_e: int,
+              block_v: int, size: int):
+    """The `[2, size]` block schedule of a dst-sorted column, in numpy
+    (`xp=np`, ingress) or traced jnp (`xp=jnp`, per superstep).
+
+    Per edge block j, the dst-block range [fb_j, lb_j] of its real
+    destinations (`< num_segments`); all-sentinel blocks sort last and get
+    an out-of-range range.  Both bounds are nondecreasing, so dst block i
+    is hit by exactly the edge blocks in [searchsorted(lb, i, left),
+    searchsorted(fb, i, right)).  Each dst block takes max(1, hits) visits;
+    visit g belongs to the dst block whose visit range holds g.
+    """
+    e = dst_sorted.shape[0]
+    n_e = max(1, -(-e // block_e))
+    n_v = -(-num_segments // block_v)
+    d = xp.pad(dst_sorted.astype(np.int32), (0, n_e * block_e - e),
+               constant_values=_DST_SENTINEL).reshape(n_e, block_e)
+    real = d < num_segments
+    none = np.int32(_DST_SENTINEL // block_v)
+    fb = xp.where(real, d, _DST_SENTINEL).min(axis=1) // block_v
+    lb = xp.where(real.any(axis=1),
+                  xp.where(real, d, -1).max(axis=1) // block_v, none)
+    blocks = xp.arange(n_v, dtype=np.int32)
+    lo = xp.searchsorted(lb, blocks, side="left").astype(np.int32)
+    hits = xp.searchsorted(fb, blocks, side="right").astype(np.int32) - lo
+    hits = xp.maximum(hits, 0)
+    ends = xp.cumsum(xp.maximum(hits, 1)).astype(np.int32)   # inclusive
+    g = xp.arange(size, dtype=np.int32)
+    vb = xp.minimum(xp.searchsorted(ends, g, side="right"),
+                    n_v - 1).astype(np.int32)
+    k = g - (ends[vb] - xp.maximum(hits[vb], 1))
+    eb = xp.where(k < hits[vb], lo[vb] + k, n_e).astype(np.int32)
+    return xp.stack([vb, eb])
 
 
 def build_block_table(dst_sorted: np.ndarray, num_segments: int,
-                      block_e: int, block_v: int) -> np.ndarray:
-    """Host-side ingress step: for each dst block, the list of edge blocks
-    whose (sorted) dst range intersects it, padded with n_edge_blocks."""
-    e = dst_sorted.shape[0]
-    n_e = -(-e // block_e)
-    n_v = -(-num_segments // block_v)
-    pad = n_e * block_e - e
-    d = np.concatenate([dst_sorted, np.full(pad, _DST_SENTINEL,
-                                            dst_sorted.dtype)])
-    first = d.reshape(n_e, block_e).min(axis=1)
-    last = d.reshape(n_e, block_e).max(axis=1)
-    # padded tail edges carry sentinel dst; clip to real values present
-    last = np.minimum(last, num_segments * 2)
-    rows = []
-    for i in range(n_v):
-        lo, hi = i * block_v, (i + 1) * block_v
-        hits = np.flatnonzero((last >= lo) & (first < hi))
-        rows.append(hits)
-    width = max(1, max(len(r) for r in rows))
-    table = np.full((n_v, width), n_e, np.int32)
-    for i, r in enumerate(rows):
-        table[i, :len(r)] = r
-    return table
+                      block_e: int = BLOCK_E,
+                      block_v: int = BLOCK_V) -> np.ndarray:
+    """Host-side ingress step: the block schedule of a STATIC dst-sorted
+    column, padded to the static length `n_edge_blocks + n_dst_blocks`
+    (the sorted-column bound), so partitions with equal padded edge counts
+    and segment spaces carry equal-shape schedules (stackable per shard)."""
+    dst_sorted = np.asarray(dst_sorted)
+    if np.any(np.diff(dst_sorted) < 0):
+        raise ValueError("build_block_table needs a dst-sorted column")
+    return _schedule(np, dst_sorted, num_segments, block_e, block_v,
+                     table_length(dst_sorted.shape[0], num_segments,
+                                  block_e, block_v))
 
 
-def dynamic_block_table(dst: jnp.ndarray, num_segments: int, block_e: int,
-                        block_v: int) -> jnp.ndarray:
-    """ON-DEVICE per-superstep pruning pass for DATA-DEPENDENT destinations.
+def table_length(num_edges: int, num_segments: int, block_e: int = BLOCK_E,
+                 block_v: int = BLOCK_V) -> int:
+    """Static schedule length for a dst-sorted column: every visit either
+    opens a dst block or crosses into the next edge block."""
+    return max(1, -(-num_edges // block_e)) + -(-num_segments // block_v)
+
+
+def dynamic_block_table(dst: jnp.ndarray, num_segments: int,
+                        block_e: int = BLOCK_E,
+                        block_v: int = BLOCK_V) -> jnp.ndarray:
+    """ON-DEVICE per-superstep schedule for DATA-DEPENDENT destinations.
 
     `dst [E] int32` is a gathered tile's destination column, SORTED
     ascending, with invalid lanes carrying a sentinel `>= num_segments`
-    (they sort past every real destination).  The same intersection test as
-    the ingress-time `build_block_table` runs in-graph with blocked
-    reductions:
-
-      1. reshape the (sentinel-padded) dst column to `[n_e, block_e]` and
-         reduce each edge block to its dst `[first, last]` range;
-      2. a (dst block, edge block) pair is visited iff the ranges intersect
-         (`last >= lo & first < hi`); the sentinel makes all-invalid blocks
-         intersect nothing;
-      3. each row's hits compact to the left via a sort of
-         `where(hit, block_id, n_e)` — rows stay padded with `n_e`, the
-         kernel's skip sentinel, and entries stay in ascending edge-block
-         order (the same layout the host-side table produces).
-
-    The table width is the STATIC worst case `n_e` (every edge block hits),
-    so the shape is jit-stable; pruning shows up as sentinel-padded rows the
-    kernel's `@pl.when` skips, not as a smaller grid.  Returns
-    `[n_v, n_e] int32`.
+    (they sort past every real destination).  The same construction as the
+    ingress-time `build_block_table` runs in-graph (blocked reductions and
+    binary searches); all-sentinel edge blocks intersect nothing and are
+    never visited.  The length is the static sorted-column bound, so the
+    shape is jit-stable; pruning shows up as fewer real visits and more
+    trailing sentinel visits, not as a smaller grid.  Returns `[2, G]`.
     """
-    e = dst.shape[0]
-    n_e = -(-e // block_e)
-    n_v = -(-num_segments // block_v)
-    d = jnp.pad(dst.astype(jnp.int32), (0, n_e * block_e - e),
-                constant_values=_DST_SENTINEL).reshape(n_e, block_e)
-    real = d < num_segments
-    first = d.min(axis=1)                         # [n_e]; sentinel if empty
-    last = jnp.where(real, d, -1).max(axis=1)     # [n_e] tightest real dst
-    lo = jnp.arange(n_v, dtype=jnp.int32) * block_v         # [n_v]
-    # All-sentinel blocks are excluded by the MASKED `last` (= -1, below
-    # every `lo`), not by `first`: the tile sentinel `num_segments` can
-    # still fall inside the last dst block's padded range when
-    # num_segments is not a multiple of block_v.
-    hit = ((last[None, :] >= lo[:, None])
-           & (first[None, :] < (lo + block_v)[:, None]))    # [n_v, n_e]
-    ids = jnp.arange(n_e, dtype=jnp.int32)
-    return jnp.sort(jnp.where(hit, ids[None, :], n_e), axis=1)
+    return _schedule(jnp, dst, num_segments, block_e, block_v,
+                     table_length(dst.shape[0], num_segments, block_e,
+                                  block_v))
 
 
 def block_table_occupancy(table, n_edge_blocks: int) -> float:
-    """Visited-block fraction of a prefetch table vs the FULL table: the
+    """Visited-pair fraction of a block schedule vs the FULL table: the
     share of the `n_v * n_edge_blocks` (dst block, edge block) pairs the
-    kernel actually computes (table entries below the `n_edge_blocks`
-    skip sentinel).  The denominator is the full pair count, not the
-    table width — `build_block_table` rows are already narrower than
-    `n_edge_blocks`.  1.0 is the degenerate `full_block_table`; the
-    pruning diagnostics in `partition_quality` and `bench_frontier`
-    report this number."""
+    kernel actually computes (visits below the `n_edge_blocks` skip
+    sentinel).  1.0 is the degenerate `full_block_table`; the pruning
+    diagnostics in `partition_quality` and `bench_frontier` report this
+    number."""
     table = np.asarray(table)
-    visited = int(np.sum(table < n_edge_blocks))
-    return visited / (table.shape[0] * max(n_edge_blocks, 1))
+    visited = int(np.sum(table[1] < n_edge_blocks))
+    n_v = int(table[0].max()) + 1
+    return visited / (n_v * max(n_edge_blocks, 1))
 
 
-def full_block_table(num_edges: int, num_segments: int, block_e: int,
-                     block_v: int) -> np.ndarray:
-    """Degenerate block table: every dst block visits every edge block.
+def full_block_table(num_edges: int, num_segments: int,
+                     block_e: int = BLOCK_E,
+                     block_v: int = BLOCK_V) -> np.ndarray:
+    """Degenerate schedule: every dst block visits every edge block.
 
-    DEPRECATED as a public entry point — the frontier tile combine now
-    routes through the plan's kernel stage (`repro.core.plan.KernelPlan`),
-    which builds the on-device `dynamic_block_table` by default.  This
-    table remains only as the documented fallback when the dynamic pruning
-    pass is disabled (`KernelPlan(dynamic_table=False)` /
-    `tile_segment_combine_pallas(.., dynamic=False)`): same kernel
-    machinery (grid, prefetch indexing, accumulation), no skipping — rows
-    whose dst falls outside the current block contribute all-zero one-hot
-    lanes.
+    Kept only as the documented fallback when the dynamic pruning pass is
+    disabled (`KernelPlan(dynamic_table=False)` /
+    `tile_segment_combine_pallas(.., dynamic=False)`): same kernel, no
+    skipping, no sort — rows whose dst falls outside the current block
+    contribute nothing.
     """
-    n_e = -(-num_edges // block_e)
+    n_e = max(1, -(-num_edges // block_e))
     n_v = -(-num_segments // block_v)
-    return np.broadcast_to(np.arange(n_e, dtype=np.int32), (n_v, n_e)).copy()
+    return np.stack([np.repeat(np.arange(n_v, dtype=np.int32), n_e),
+                     np.tile(np.arange(n_e, dtype=np.int32), n_v)])
 
 
 def tile_segment_combine_pallas(msgs: jnp.ndarray, dst: jnp.ndarray,
                                 num_segments: int, op: str = "sum",
-                                block_e: int = 256, block_v: int = 256,
-                                interpret: bool = True,
+                                block_e: int = BLOCK_E,
+                                block_v: int = BLOCK_V,
                                 dynamic: bool = True) -> jnp.ndarray:
     """Segment-combine a gathered frontier tile (msgs [E, D] float32,
     dst [E] int32, BOTH data-dependent).
@@ -223,47 +259,75 @@ def tile_segment_combine_pallas(msgs: jnp.ndarray, dst: jnp.ndarray,
         table = jnp.asarray(full_block_table(msgs.shape[0], num_segments,
                                              block_e, block_v))
     return segment_combine_pallas(msgs, dst, table, num_segments, op,
-                                  block_e=block_e, block_v=block_v,
-                                  interpret=interpret)
+                                  block_e=block_e, block_v=block_v)
+
+
+def _combine_call(sched, dst, msgs, prev, *, op: str, block_e: int,
+                  block_v: int, n_edge_blocks: int, interpret: bool):
+    """One kernel call over a `[3, C]` schedule slice; `prev` ([D, V_pad])
+    is aliased to the output, so dst blocks this slice never visits keep
+    their values."""
+    d = msgs.shape[0]
+
+    def eblock(g, s):
+        return 0, jnp.minimum(s[1, g], n_edge_blocks - 1)
+
+    def vblock(g, s):
+        return 0, s[0, g]
+
+    return pl.pallas_call(
+        functools.partial(_kernel, op=op, block_v=block_v,
+                          n_edge_blocks=n_edge_blocks),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(sched.shape[1],),
+            in_specs=[pl.BlockSpec((1, block_e), eblock),
+                      pl.BlockSpec((d, block_e), eblock),
+                      pl.BlockSpec((d, block_v), vblock)],
+            out_specs=pl.BlockSpec((d, block_v), vblock),
+        ),
+        out_shape=jax.ShapeDtypeStruct(prev.shape, jnp.float32),
+        input_output_aliases={3: 0},
+        interpret=interpret,
+    )(sched, dst, msgs, prev)
 
 
 @functools.partial(jax.jit, static_argnames=("num_segments", "op", "block_e",
-                                             "block_v", "interpret"))
+                                             "block_v"))
 def segment_combine_pallas(msgs: jnp.ndarray, dst: jnp.ndarray,
                            table: jnp.ndarray, num_segments: int,
-                           op: str = "sum", block_e: int = 256,
-                           block_v: int = 256, interpret: bool = True
-                           ) -> jnp.ndarray:
-    """msgs [E, D] (dst-sorted), dst [E] int32, table from any of the
-    block-table builders above.  Returns [num_segments, D]."""
-    e, d_feat = msgs.shape
-    n_e = -(-e // block_e)
-    n_v = -(-num_segments // block_v)
-    v_pad = n_v * block_v
-    e_pad = n_e * block_e
-    # pad edges with an out-of-range dst so their one-hot rows are all-zero
-    msgs = jnp.pad(msgs, ((0, e_pad - e), (0, 0)))
-    dst = jnp.pad(dst.astype(jnp.int32), (0, e_pad - e),
-                  constant_values=_DST_SENTINEL)
-    # append one dummy zero edge block for padded table entries
-    msgs = jnp.concatenate([msgs, jnp.zeros((block_e, d_feat), msgs.dtype)])
-    dst = jnp.concatenate([dst, jnp.full((block_e,), _DST_SENTINEL,
-                                         jnp.int32)])
+                           op: str = "sum", block_e: int = BLOCK_E,
+                           block_v: int = BLOCK_V) -> jnp.ndarray:
+    """msgs [E, D] (dst-sorted), dst [E] int32, table `[2, G]` from any of
+    the schedule functions above.  Returns [num_segments, D] float32.
 
-    width = table.shape[1]
-    grid = (n_v, width)
-    out = pl.pallas_call(
-        functools.partial(_kernel, op=op, block_v=block_v, n_edge_blocks=n_e),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((block_e,), lambda i, j, t: (t[i, j],)),
-                pl.BlockSpec((block_e, d_feat), lambda i, j, t: (t[i, j], 0)),
-            ],
-            out_specs=pl.BlockSpec((block_v, d_feat), lambda i, j, t: (i, 0)),
-        ),
-        out_shape=jax.ShapeDtypeStruct((v_pad, d_feat), jnp.float32),
-        interpret=interpret,
-    )(table, dst, msgs)
-    return out[:num_segments]
+    Compiled by Mosaic when lowered for a TPU and run by the Pallas
+    interpreter on CPU (`repro.kernels.on_backend`)."""
+    e, d_feat = msgs.shape
+    n_e = max(1, -(-e // block_e))
+    n_v = -(-num_segments // block_v)
+    pad = n_e * block_e - e
+    msgs_t = jnp.pad(msgs.astype(jnp.float32), ((0, pad), (0, 0))).T
+    dst_row = jnp.pad(dst.astype(jnp.int32), (0, pad),
+                      constant_values=_DST_SENTINEL)[None, :]
+    vb, eb = table[0].astype(jnp.int32), table[1].astype(jnp.int32)
+    g = vb.shape[0]
+    chunk = min(g, MAX_VISITS)
+    n_chunks = -(-g // chunk)
+    tail = n_chunks * chunk - g
+    vb = jnp.pad(vb, (0, tail), mode="edge")
+    eb = jnp.pad(eb, (0, tail), constant_values=n_e)
+    first = jnp.concatenate([jnp.ones((1,), bool), vb[1:] != vb[:-1]])
+    starts = jnp.arange(vb.shape[0]) % chunk == 0
+    mode = jnp.where(first, 1, jnp.where(starts, 2, 0)).astype(jnp.int32)
+    sched = jnp.stack([vb, eb, mode]).reshape(3, n_chunks, chunk)
+    out = jnp.full((d_feat, n_v * block_v), _OP_IDENTITY[op], jnp.float32)
+    call = functools.partial(_combine_call, op=op, block_e=block_e,
+                             block_v=block_v, n_edge_blocks=n_e)
+
+    def run(c, acc):
+        return on_backend(call, sched[:, c], dst_row, msgs_t, acc)
+
+    out = jax.lax.fori_loop(0, n_chunks, run, out) if n_chunks > 1 \
+        else run(0, out)
+    return out[:, :num_segments].T

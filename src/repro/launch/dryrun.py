@@ -22,6 +22,7 @@ from pathlib import Path
 
 import jax
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import all_cells
 from repro.launch.mesh import make_production_mesh
 from repro.launch import roofline as rl
@@ -154,6 +155,7 @@ def main():
     ap.add_argument("--out", default="results/dryrun")
     ap.add_argument("--save-hlo", action="store_true")
     args = ap.parse_args()
+    enable_compile_cache()
 
     outdir = Path(args.out)
     outdir.mkdir(parents=True, exist_ok=True)

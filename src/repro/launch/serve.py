@@ -15,6 +15,7 @@ import time
 import jax
 import jax.numpy as jnp
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config
 from repro.launch.train import reduced_lm_config
 from repro.models import transformer as tfm
@@ -28,6 +29,7 @@ def main(argv=None):
     ap.add_argument("--gen", type=int, default=32)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg, family = get_config(args.arch)
     assert family == "lm"

@@ -21,6 +21,7 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.checkpoint.manager import CheckpointManager
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config
 from repro.data.tokens import TokenStream
 from repro.dist import sharding as shd
@@ -60,6 +61,7 @@ def main(argv=None):
                     help="use the arch's real config (needs real hardware)")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg, family = get_config(args.arch)
     assert family == "lm", "train.py drives LM archs; see examples/ for others"

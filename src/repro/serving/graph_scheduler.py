@@ -184,12 +184,15 @@ class GraphQueryBatcher:
     def _make_tick(self, part):
         engine, steps = self.engine, self.steps_per_tick
 
-        def tick(state):
+        def tick(part, state):
             for _ in range(steps):
                 state = engine.superstep(part, state)
             return state
 
-        return jax.jit(tick)
+        # the partition is an ARGUMENT, not a closure: jit embeds closed-over
+        # arrays in the program as constants, a copy of the whole topology
+        tick = jax.jit(tick)
+        return lambda state: tick(part, state)
 
     def _make_admit(self, part):
         """ONE static-shape admission/eviction/reset call.
@@ -204,13 +207,13 @@ class GraphQueryBatcher:
         n, slots = part.num_masters, part.num_slots
         identity = p.monoid.identity
 
-        def admit(state, src, lanes, flags):
+        def admit(aux, state, src, lanes, flags):
             mask = jnp.zeros(D, dtype=bool).at[lanes].set(True, mode="drop")
-            init_vd = p.init_vertex_data(n, part.aux)
+            init_vd = p.init_vertex_data(n, aux)
             vd = state.vertex_data
             bmask = mask.reshape((1, D) + (1,) * (vd.ndim - 2))
             vd = jnp.where(bmask, init_vd, vd)
-            sd0 = jnp.asarray(p.init_scatter_data(n, part.aux), p.msg_dtype)
+            sd0 = jnp.asarray(p.init_scatter_data(n, aux), p.msg_dtype)
             sd_init = jnp.full((slots,) + sd0.shape[1:], identity,
                                p.msg_dtype).at[:n].set(sd0)
             sd = jnp.where(mask[None, :], sd_init, state.scatter_data)
@@ -228,7 +231,7 @@ class GraphQueryBatcher:
                              rows, identity)
             sd = sd.at[src].set(rows, mode="drop")
             if p.seed_sources is not None:
-                vd, sd = p.seed_sources(vd, sd, src, lanes, part.aux)
+                vd, sd = p.seed_sources(vd, sd, src, lanes, aux)
             else:
                 vd = vd.at[src, lanes].set(0.0, mode="drop")
                 sd = sd.at[src, lanes].set(0.0, mode="drop")
@@ -238,7 +241,9 @@ class GraphQueryBatcher:
                 state, vertex_data=vd, scatter_data=sd,
                 active_scatter=active, lane_active=lane_active)
 
-        return jax.jit(admit)
+        admit = jax.jit(admit)   # aux as an argument, as in `_make_tick`
+        return lambda state, src, lanes, flags: admit(part.aux, state, src,
+                                                      lanes, flags)
 
     def _make_dist_admit(self, ag):
         """Distributed admission: same contract, stacked `[k, ...]` state.
@@ -247,13 +252,19 @@ class GraphQueryBatcher:
         slot on exactly the shard that masters it (sentinel `num_slots`
         everywhere else), so the vmapped per-shard body is identical to the
         single-shard one.  `lane_active` stays replicated: row 0 is updated
-        and broadcast.
+        and broadcast.  Like the tick, the call runs under shard_map over
+        the engine's mesh, so every stacked operand keeps the state's
+        row-per-device placement.
         """
+        from jax.sharding import PartitionSpec as P
+
+        from repro.dist.sharding import shard_map
+        engine = self.engine
         p, D = self.program, self.num_lanes
         cap, slots = ag.cap, ag.num_slots
         identity = p.monoid.identity
-        aux = {"out_degree": jnp.asarray(ag.out_degree),
-               "global_id": jnp.asarray(
+        aux = {"out_degree": engine._put_rows(ag.out_degree),
+               "global_id": engine._put_rows(
                    ag.new2old.reshape(ag.k, cap).astype(np.float32))}
 
         def one_shard(vd, sd, act, aux_i, src_i, lanes, mask):
@@ -280,7 +291,7 @@ class GraphQueryBatcher:
             act = act.at[src_i].set(True, mode="drop")
             return vd, sd, act
 
-        def admit(state, src, lanes, flags):
+        def admit_shard(state, aux, src, lanes, flags):
             mask = jnp.zeros(D, dtype=bool).at[lanes].set(True, mode="drop")
             vd, sd, act = jax.vmap(
                 lambda v, s, a, x, si: one_shard(v, s, a, x, si, lanes, mask)
@@ -292,7 +303,12 @@ class GraphQueryBatcher:
                 state, vertex_data=vd, scatter_data=sd, active_scatter=act,
                 lane_active=la)
 
-        return jax.jit(admit)
+        rows = engine._row_sharding.spec
+        fn = jax.jit(shard_map(admit_shard, mesh=engine.mesh,
+                               in_specs=(rows, rows, rows, P(), P()),
+                               out_specs=rows))
+        return lambda state, src, lanes, flags: fn(state, aux, src, lanes,
+                                                   flags)
 
     # --------------------------------------------------------------- serving
     def submit(self, source: int, *, kind: Optional[str] = None,

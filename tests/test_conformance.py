@@ -565,22 +565,22 @@ def _check_dynamic_table(e, v, d, op, valid_frac, seed, block=64):
         np.testing.assert_array_equal(fix(dyn), fix(want))
         np.testing.assert_array_equal(fix(full), fix(want))
     # coverage: every (dst block, edge block) pair with a real dst in the
-    # dst block's range appears in the sorted tile's table row
+    # dst block's range is among the sorted tile's visits of that block
     ds = np.sort(dst)
     n_e = -(-e // block)
     table = np.asarray(dynamic_block_table(jnp.asarray(ds), v, block, block))
     dpad = np.concatenate([ds, np.full(n_e * block - e, v, np.int32)])
     dpad = dpad.reshape(n_e, block)
-    for i in range(table.shape[0]):
+    for i in range(-(-v // block)):
         lo, hi = i * block, (i + 1) * block
         need = {j for j in range(n_e)   # real dsts only: sentinels (>= v)
                 if ((dpad[j] >= lo) & (dpad[j] < hi)
                     & (dpad[j] < v)).any()}
-        have = {int(x) for x in table[i] if x < n_e}
+        have = {int(x) for x in table[1][table[0] == i] if x < n_e}
         assert need <= have
     # pruning: all-sentinel edge blocks never appear anywhere
     empty = {j for j in range(n_e) if (dpad[j] >= v).all()}
-    seen = {int(x) for x in table.ravel() if x < n_e}
+    seen = {int(x) for x in table[1] if x < n_e}
     assert not (empty & seen)
 
 

@@ -17,7 +17,8 @@ import jax.numpy as jnp
 from repro.core import algorithms
 from repro.core.engine import DevicePartition, EngineState, GREEngine
 from repro.core.frontier import (bucket_caps, bucketed_scatter_combine,
-                                 default_cap, gather_frontier_edge_tile)
+                                 compact_indices, default_cap,
+                                 gather_frontier_edge_tile)
 from repro.graph.generators import circulant_graph, rmat_edges
 from repro.graph.structures import Graph
 
@@ -33,6 +34,21 @@ def _run(program, part, source=None, frontier="auto", cap=None,
     eng = GREEngine(program, frontier=frontier, frontier_cap=cap)
     out = eng.run(part, eng.init_state(part, source=source), max_steps)
     return np.asarray(out.vertex_data)
+
+
+@pytest.mark.parametrize("n,size,density", [
+    (5, 3, 0.5), (1024, 100, 0.05), (1025, 2000, 0.3), (3000, 7, 0.9),
+    (1, 1, 1.0), (4096, 10, 0.0)])
+def test_compact_indices_matches_nonzero(n, size, density):
+    """The frontier's compaction equals `jnp.nonzero` with a static size:
+    the first `size` live slots in ascending order, then the fill value —
+    across row boundaries of its two-level prefix sum, overflow (more live
+    slots than `size`) and an empty mask."""
+    mask = jnp.asarray(np.random.default_rng(n).random(n) < density)
+    got = compact_indices(mask, size, n)
+    want = jnp.nonzero(mask, size=size, fill_value=n)[0]
+    assert got.dtype == want.dtype
+    np.testing.assert_array_equal(got, want)
 
 
 def _assert_strategies_agree(program, part, source=None, cap=None):

@@ -53,13 +53,17 @@ def test_block_table_covers_all_edges():
     dst = np.sort(RNG.integers(0, 1000, 5000)).astype(np.int32)
     table = build_block_table(dst, 1000, block_e=256, block_v=128)
     n_e = -(-5000 // 256)
-    # every edge block with any dst in a v-range appears in that row
-    for i in range(table.shape[0]):
+    n_v = -(-1000 // 128)
+    # visits are grouped by dst block, in order, each block at least once
+    assert np.all(np.diff(table[0]) >= 0)
+    assert set(table[0].tolist()) == set(range(n_v))
+    # every edge block with any dst in a v-range is visited for that block
+    for i in range(n_v):
         lo, hi = i * 128, (i + 1) * 128
         need = {int(j) for j in range(n_e)
                 if ((dst[j * 256:(j + 1) * 256] >= lo)
                     & (dst[j * 256:(j + 1) * 256] < hi)).any()}
-        have = {int(x) for x in table[i] if x < n_e}
+        have = {int(x) for x in table[1][table[0] == i] if x < n_e}
         assert need <= have
 
 

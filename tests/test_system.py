@@ -44,3 +44,28 @@ def test_paper_workload_end_to_end():
     out = eng.run(sp, eng.init_state(sp), max_steps=30)
     pr = np.asarray(out.vertex_data)
     assert np.isfinite(pr).all() and pr.min() >= 0.15 - 1e-5
+
+
+def test_compile_cache_location(monkeypatch, tmp_path):
+    """The persistent compile cache goes where JAX_COMPILATION_CACHE_DIR
+    says (JAX reads the variable itself, so nothing is set in code), and
+    otherwise to the fixed `.jax_cache` at the repository root."""
+    from pathlib import Path
+
+    import jax
+    from repro import compile_cache
+    was = jax.config.jax_compilation_cache_dir
+    try:
+        jax.config.update("jax_compilation_cache_dir", None)
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        repo_cache = Path(__file__).resolve().parents[1] / ".jax_cache"
+        assert compile_cache.REPO_CACHE == repo_cache
+        assert compile_cache.enable_compile_cache() == str(repo_cache)
+        assert jax.config.jax_compilation_cache_dir == str(repo_cache)
+
+        jax.config.update("jax_compilation_cache_dir", None)
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert compile_cache.enable_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir is None
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)

@@ -1,0 +1,129 @@
+"""Compile the main path for a described TPU v5e (no chip needed).
+
+The TPU compiler is installed alongside jax, so the Pallas combine, the
+single-chip superstep loop and the 4-chip distributed run are lowered and
+compiled here for a `v5e:2x2` topology: what Mosaic or XLA would refuse on
+the chip (block layouts, VMEM/SMEM budgets, partitioning) fails here.
+Nothing runs.  The topology is described inside a fixture, never at import
+time (only one process at a time may load the TPU library).
+"""
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
+
+from repro.core import algorithms
+from repro.core.agent_graph import build_agent_graph
+from repro.core.dist_engine import DistGREEngine
+from repro.core.engine import DevicePartition, GREEngine
+from repro.core.partition import hash_partition
+from repro.graph.generators import rmat_edges
+from repro.kernels import segment_combine as sc
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    # a compile for a described chip is written to the persistent cache but
+    # cannot be read back without one: keep these compiles out of it
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        yield topologies.get_topology_desc(platform="tpu",
+                                           topology_name="v5e:2x2")
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _abstract(tree, sharding):
+    return jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding),
+        tree)
+
+
+@pytest.mark.parametrize("d", [1, 64])
+@pytest.mark.parametrize("op", ["sum", "min"])
+@pytest.mark.parametrize("table", ["static", "dynamic"])
+def test_pallas_combine_compiles(one_chip, table, op, d):
+    e, v = 8192, 2048
+    s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    msgs, dst = s((e, d), jnp.float32), s((e,), jnp.int32)
+    if table == "static":
+        fn = lambda m, i, t: sc.segment_combine_pallas(m, i, t, v, op)
+        args = (msgs, dst, s((2, sc.table_length(e, v)), jnp.int32))
+    else:
+        fn = lambda m, i: sc.tile_segment_combine_pallas(m, i, v, op)
+        args = (msgs, dst)
+    hlo = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in hlo
+
+
+@pytest.mark.parametrize("name,source", [("pagerank", None), ("sssp", 0)])
+def test_engine_run_with_pallas_compiles(one_chip, name, source):
+    """PageRank takes the dense scan (ingress-time block table); SSSP the
+    bucketed frontier tiles (per-superstep table) and the dense fallback."""
+    g = rmat_edges(scale=9, edge_factor=8, seed=1, weights=True).dedup()
+    part = DevicePartition.from_graph(g)
+    eng = GREEngine(getattr(algorithms, f"{name}_program")(),
+                    use_pallas=True)
+    state = eng.init_state(part, source=source)
+    hlo = GREEngine.run.lower(eng, _abstract(part, one_chip),
+                              _abstract(state, one_chip),
+                              16).compile().as_text()
+    assert "tpu_custom_call" in hlo
+
+
+def test_dist_pipelined_run_compiles_for_four_chips(topo):
+    """`DistGREEngine.make_run` (pipelined exchange, SSSP) on a 4-device
+    mesh of the described chips: the flush collectives partition."""
+    g = rmat_edges(scale=9, edge_factor=8, seed=2, weights=True).dedup()
+    ag = build_agent_graph(g, hash_partition(g, 4), 4)
+    prog = algorithms.sssp_program()
+    host = DistGREEngine(prog, jax.make_mesh((1,), ("graph",)),
+                         exchange="pipelined")
+    topo_arrays = host.device_topology(ag)
+    state = host.init_state(ag, source=0)
+    mesh = jax.sharding.Mesh(np.asarray(topo.devices[:4]), ("graph",))
+    rows = NamedSharding(mesh, P("graph"))
+    eng = DistGREEngine(prog, mesh, ("graph",), exchange="pipelined")
+    compiled = eng.make_run(ag, max_steps=16).lower(
+        _abstract(topo_arrays, rows), _abstract(state, rows)).compile()
+    hlo = compiled.as_text()
+    assert "all-to-all" in hlo or "all-reduce" in hlo
+
+
+def test_refresh_exchange_compiles_without_sort(topo):
+    """The Agent-Graph refresh at a shard's real exchange width (32K agent
+    slots per shard) compiles with no sort: the TPU compiler lowers a
+    sub-32-bit scatter (the activity flags) through a sort, which costs
+    seconds of compile per scatter at this width."""
+    from types import SimpleNamespace
+
+    from repro.core.exchange import refresh_scatter_agents
+    from repro.dist.sharding import shard_map
+    k, x, slots = 4, 8192, 65536
+    mesh = jax.sharding.Mesh(np.asarray(topo.devices[:4]), ("graph",))
+    rows = NamedSharding(mesh, P("graph"))
+
+    def shard(send, recv, sd, act):
+        t = SimpleNamespace(scat_send_master=send[0], scat_recv_slot=recv[0])
+        sd, act = refresh_scatter_agents(t, sd[0], act[0], "graph")
+        return sd[None], act[None]
+
+    fn = jax.jit(shard_map(shard, mesh=mesh, in_specs=(P("graph"),) * 4,
+                           out_specs=P("graph")))
+    s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=rows)
+    hlo = fn.lower(s((k, k, x), jnp.int32), s((k, k, x), jnp.int32),
+                   s((k, slots), jnp.float32),
+                   s((k, slots), jnp.bool_)).compile().as_text()
+    assert "all-to-all" in hlo
+    assert " sort(" not in hlo
