@@ -122,11 +122,18 @@ class DevicePartition:
         in place over the filled prefix — bitwise-identical columns, but
         peak host state is the padded output columns plus ONE chunk, with
         no intermediate full edge-list copy (docs/partitioning.md).
+
+        The four host phases run under `repro.spans` spans, which a
+        profile shows and `spans.recording()` keeps: `gre.ingress.sort`
+        (the dst sort), `gre.ingress.csr` (`csr_layout`),
+        `gre.ingress.buckets` (`degree_buckets`) and
+        `gre.ingress.block_table` (`build_block_table`).
         """
         from repro.graph.structures import (DEFAULT_BUCKET_BOUNDS,
                                             csr_layout, degree_buckets,
                                             pad_edges, sort_edges_by_dst)
         from repro.kernels.segment_combine import build_block_table
+        from repro.spans import span
         source = graph if hasattr(graph, "chunks") else (
             graph.chunk_source(chunk_size) if chunk_size else None)
         if source is not None:
@@ -152,30 +159,37 @@ class DevicePartition:
                 out_deg += np.bincount(s, minlength=v)
                 cur = hi
             if sort_by_dst:
-                order = np.argsort(pdst[:e], kind="stable")
-                psrc[:e] = psrc[:e][order]
-                pdst[:e] = pdst[:e][order]
-                for k in props:
-                    props[k][:e] = props[k][:e][order]
+                with span("gre.ingress.sort"):
+                    order = np.argsort(pdst[:e], kind="stable")
+                    psrc[:e] = psrc[:e][order]
+                    pdst[:e] = pdst[:e][order]
+                    for k in props:
+                        props[k][:e] = props[k][:e][order]
             out_deg = out_deg.astype(np.float32)
         else:
             if transpose:
                 graph = graph.reversed()
             src, dst, props = graph.src, graph.dst, dict(graph.edge_props)
             if sort_by_dst:
-                src, dst, props, _ = sort_edges_by_dst(src, dst, props)
+                with span("gre.ingress.sort"):
+                    src, dst, props, _ = sort_edges_by_dst(src, dst, props)
             v = graph.num_vertices
             e_pad = pad_to or (graph.num_edges + edge_slack)
             psrc, pdst, mask = pad_edges(src, dst, e_pad, pad_vertex=v)
             props = {k: np.pad(p, (0, e_pad - graph.num_edges))
                      for k, p in props.items()}
             out_deg = graph.out_degree().astype(np.float32)
-        indptr, eidx, max_deg = csr_layout(psrc, mask, v + 1)
-        bucket_id, sizes, max_degs = degree_buckets(
-            indptr, v + 1, bounds=tuple(bucket_bounds or
-                                        DEFAULT_BUCKET_BOUNDS))
-        table = (jnp.asarray(build_block_table(pdst, v + 1))
-                 if sort_by_dst else None)
+        with span("gre.ingress.csr"):
+            indptr, eidx, max_deg = csr_layout(psrc, mask, v + 1)
+        with span("gre.ingress.buckets"):
+            bucket_id, sizes, max_degs = degree_buckets(
+                indptr, v + 1, bounds=tuple(bucket_bounds or
+                                            DEFAULT_BUCKET_BOUNDS))
+        table = None
+        if sort_by_dst:
+            with span("gre.ingress.block_table"):
+                table = build_block_table(pdst, v + 1)
+            table = jnp.asarray(table)
         return DevicePartition(
             src=jnp.asarray(psrc), dst=jnp.asarray(pdst),
             edge_mask=jnp.asarray(mask), num_masters=v, num_slots=v + 1,
@@ -325,6 +339,14 @@ class EngineState:
     and reseed the lane between supersteps.  Enabled via
     `init_state(..., lane_tracking=True)`; None keeps the classic pytree
     structure (zero cost, zero recompilation for non-serving runs).
+
+    `counters` is the OPTIONAL per-superstep work record ([rows, 3] int32,
+    None by default, which keeps the pytree and the compiled run as they
+    are): row i holds superstep i's active vertices, active out-edges (the
+    out-degrees of the active sources summed) and the edges the chosen
+    scatter route scanned (`frontier.superstep_counts`, columns named by
+    `frontier.COUNTERS`).  Rows of supersteps that did not run stay 0.
+    Enabled via `init_state(..., counters=rows)`; single-shard runs only.
     """
 
     vertex_data: jnp.ndarray     # [num_masters, *V]
@@ -332,6 +354,7 @@ class EngineState:
     active_scatter: jnp.ndarray  # [num_slots] bool
     step: jnp.ndarray            # scalar int32 superstep counter
     lane_active: Optional[jnp.ndarray] = None  # [D] bool, serving only
+    counters: Optional[jnp.ndarray] = None     # [rows, 3] int32, opt-in
 
 
 class GREEngine:
@@ -547,7 +570,8 @@ class GREEngine:
 
     # ------------------------------------------------------------------ init
     def init_state(self, part: DevicePartition, source=None,
-                   lane_tracking: bool = False) -> EngineState:
+                   lane_tracking: bool = False,
+                   counters: int = 0) -> EngineState:
         """`source` may be a single vertex id, or — for multi-source batched
         traversal programs with `payload_shape=(D,)` — a length-D sequence:
         source d seeds payload lane d, so ONE pass answers D roots.
@@ -562,6 +586,11 @@ class GREEngine:
         `lane_tracking=True` attaches the per-lane halt tracker
         (`EngineState.lane_active`, seeded lanes start active); requires a
         multi-source program exposing `lane_activates`.
+
+        `counters=rows` attaches the per-superstep work record
+        (`EngineState.counters`, `[rows, 3]` int32 zeros); `run` refuses
+        fewer rows than its `max_steps`.  It reads the partition's CSR
+        layout, so a partition without one is refused.
         """
         p = self.program
         n, s = part.num_masters, part.num_slots
@@ -601,8 +630,13 @@ class GREEngine:
             raise ValueError("lane_tracking needs a multi-source (sequence) "
                              "`source` and a program with `lane_activates` "
                              "(payload_shape=(D,))")
-        state = EngineState(vertex_data, scatter_data, active,
-                            jnp.zeros((), jnp.int32), lane_active)
+        if counters and part.csr_indptr is None:
+            raise ValueError("counters need the partition's CSR layout "
+                             "(csr_indptr), which this partition lacks")
+        state = EngineState(
+            vertex_data, scatter_data, active, jnp.zeros((), jnp.int32),
+            lane_active,
+            jnp.zeros((counters, 3), jnp.int32) if counters else None)
         if self._auto_plan_pending:
             # plan="auto-tuned": the seeded state is the last eager point
             # before a jitted run trace fixes the static tile shapes, and
@@ -712,25 +746,27 @@ class GREEngine:
         p = self.program
         eprop = (part.edge_props[p.needs_edge_prop]
                  if p.needs_edge_prop else None)
-        gathered = jnp.take(state.scatter_data, part.src, axis=0,
-                            fill_value=p.monoid.identity)
-        msgs = p.scatter_msg(gathered, eprop)
-        if self.dense_frontier:
-            msgs = msgs.astype(p.msg_dtype)
-        else:
-            live = jnp.take(state.active_scatter, part.src, axis=0,
-                            fill_value=False) & part.edge_mask
-            live = live.reshape(live.shape + (1,) * (msgs.ndim - live.ndim))
-            msgs = jnp.where(live, msgs.astype(p.msg_dtype),
-                             p.monoid.identity)
+        with jax.named_scope("gre.scatter"):
+            gathered = jnp.take(state.scatter_data, part.src, axis=0,
+                                fill_value=p.monoid.identity)
+            msgs = p.scatter_msg(gathered, eprop)
+            if self.dense_frontier:
+                msgs = msgs.astype(p.msg_dtype)
+            else:
+                live = jnp.take(state.active_scatter, part.src, axis=0,
+                                fill_value=False) & part.edge_mask
+                live = live.reshape(live.shape + (1,) * (msgs.ndim - live.ndim))
+                msgs = jnp.where(live, msgs.astype(p.msg_dtype),
+                                 p.monoid.identity)
         nseg = num_segments or part.num_slots
         table = None
         if self.use_pallas:
             table = self._combine_table(part, nseg)
-        return segment_combine(
-            msgs, part.dst, nseg, p.monoid,
-            indices_are_sorted=part.edges_sorted_by_dst,
-            use_pallas=self.use_pallas, table=table)
+        with jax.named_scope("gre.combine"):
+            return segment_combine(
+                msgs, part.dst, nseg, p.monoid,
+                indices_are_sorted=part.edges_sorted_by_dst,
+                use_pallas=self.use_pallas, table=table)
 
     @staticmethod
     def _combine_table(part: DevicePartition, num_segments: int):
@@ -752,6 +788,7 @@ class GREEngine:
         return part.combine_table
 
     # ------------------------------------------------------------------ apply
+    @jax.named_scope("gre.apply")
     def apply(self, part: DevicePartition, state: EngineState,
               combined: jnp.ndarray) -> EngineState:
         """Phase 2: fold combine_data into vertex_data; assert_to_halt.
@@ -787,7 +824,7 @@ class GREEngine:
             lane_active = jnp.any(p.lane_activates(state.vertex_data,
                                                    combined_m), axis=0)
         return EngineState(vertex_data, scatter_data, active, state.step + 1,
-                           lane_active)
+                           lane_active, state.counters)
 
     # ------------------------------------------------------------- superstep
     def superstep(self, part: DevicePartition, state: EngineState,

@@ -56,6 +56,14 @@ pipelined exchange's per-destination-shard remote tile or master-local tile
 whatever `dst`/`edge_props` columns the partition carries, and the ⊕
 segment space is the caller's `num_segments` (compact combiner/master
 spaces for the split tiles, full slot space otherwise).
+
+Device work runs under two named scopes, which a profile shows in each
+op's name stack: `gre.scatter` (the route predicates, compaction, the
+tile gathers and the messages) and `gre.combine` (the ⊕ reductions, the
+Pallas tile combine with its pruning pass, and the fold of the bucket
+partials).  The route predicates (`fits_capacity`, `bucket_route`) are
+shared with `superstep_counts`, which counts a superstep's work for
+`EngineState.counters`, so a count always describes the branch that ran.
 """
 from __future__ import annotations
 
@@ -76,6 +84,9 @@ if TYPE_CHECKING:  # pragma: no cover - typing only, avoids a cycle
 # Density threshold for auto strategy selection: compact below ~6% active
 # (the literature's crossover for frontier-aware traversal sits at 5-10%).
 FRONTIER_DENSITY = 1.0 / 16.0
+
+# Columns of `EngineState.counters`, one row per superstep.
+COUNTERS = ("active_vertices", "active_out_edges", "edges_scanned")
 
 # Calibrated capacity head-room: cap = GROWTH x the largest frontier
 # observed during the probe supersteps (frontiers grow superstep over
@@ -218,25 +229,27 @@ def compact_scatter_combine(program: "VertexProgram", part: "DevicePartition",
     p = program
     if max_deg is None:
         max_deg = part.csr_max_deg
-    mask = state.active_scatter if frontier_mask is None else frontier_mask
-    frontier = compact_indices(mask, cap, part.num_slots)
-    eid, valid = gather_frontier_edge_tile(part, frontier, cap, max_deg)
-    # invalid lanes carry identity msgs AND the out-of-range dst sentinel:
-    # XLA scatter-reduces drop them, and the Pallas dynamic pruning pass
-    # sorts them past every real destination so their blocks prune away
-    dst = jnp.where(valid, part.dst[eid], num_segments)
-    gathered = jnp.take(state.scatter_data, frontier, axis=0,
-                        fill_value=p.monoid.identity)    # [cap, *S]
-    tile = jnp.broadcast_to(gathered[:, None],
-                            (cap, max_deg) + gathered.shape[1:])
-    flat = tile.reshape((cap * max_deg,) + gathered.shape[1:])
-    eprop = (part.edge_props[p.needs_edge_prop][eid].reshape(-1)
-             if p.needs_edge_prop else None)
-    msgs = p.scatter_msg(flat, eprop)
-    vmask = valid.reshape((-1,) + (1,) * (msgs.ndim - 1))
-    msgs = jnp.where(vmask, msgs.astype(p.msg_dtype), p.monoid.identity)
-    return _tile_combine(program, msgs, dst.reshape(-1), num_segments,
-                         kernel=kernel)
+    with jax.named_scope("gre.scatter"):
+        mask = state.active_scatter if frontier_mask is None else frontier_mask
+        frontier = compact_indices(mask, cap, part.num_slots)
+        eid, valid = gather_frontier_edge_tile(part, frontier, cap, max_deg)
+        # invalid lanes carry identity msgs AND the out-of-range dst sentinel:
+        # XLA scatter-reduces drop them, and the Pallas dynamic pruning pass
+        # sorts them past every real destination so their blocks prune away
+        dst = jnp.where(valid, part.dst[eid], num_segments)
+        gathered = jnp.take(state.scatter_data, frontier, axis=0,
+                            fill_value=p.monoid.identity)    # [cap, *S]
+        tile = jnp.broadcast_to(gathered[:, None],
+                                (cap, max_deg) + gathered.shape[1:])
+        flat = tile.reshape((cap * max_deg,) + gathered.shape[1:])
+        eprop = (part.edge_props[p.needs_edge_prop][eid].reshape(-1)
+                 if p.needs_edge_prop else None)
+        msgs = p.scatter_msg(flat, eprop)
+        vmask = valid.reshape((-1,) + (1,) * (msgs.ndim - 1))
+        msgs = jnp.where(vmask, msgs.astype(p.msg_dtype), p.monoid.identity)
+    with jax.named_scope("gre.combine"):
+        return _tile_combine(program, msgs, dst.reshape(-1), num_segments,
+                             kernel=kernel)
 
 
 def dense_masked_combine(program: "VertexProgram", part: "DevicePartition",
@@ -251,15 +264,17 @@ def dense_masked_combine(program: "VertexProgram", part: "DevicePartition",
     p = program
     eprop = (part.edge_props[p.needs_edge_prop]
              if p.needs_edge_prop else None)
-    gathered = jnp.take(state.scatter_data, part.src, axis=0,
-                        fill_value=p.monoid.identity)
-    msgs = p.scatter_msg(gathered, eprop)
-    live = jnp.take(src_mask, part.src, axis=0,
-                    fill_value=False) & part.edge_mask
-    live = live.reshape(live.shape + (1,) * (msgs.ndim - live.ndim))
-    msgs = jnp.where(live, msgs.astype(p.msg_dtype), p.monoid.identity)
-    return segment_combine(msgs, part.dst, num_segments, p.monoid,
-                           indices_are_sorted=part.edges_sorted_by_dst)
+    with jax.named_scope("gre.scatter"):
+        gathered = jnp.take(state.scatter_data, part.src, axis=0,
+                            fill_value=p.monoid.identity)
+        msgs = p.scatter_msg(gathered, eprop)
+        live = jnp.take(src_mask, part.src, axis=0,
+                        fill_value=False) & part.edge_mask
+        live = live.reshape(live.shape + (1,) * (msgs.ndim - live.ndim))
+        msgs = jnp.where(live, msgs.astype(p.msg_dtype), p.monoid.identity)
+    with jax.named_scope("gre.combine"):
+        return segment_combine(msgs, part.dst, num_segments, p.monoid,
+                               indices_are_sorted=part.edges_sorted_by_dst)
 
 
 def bucketed_scatter_combine(program: "VertexProgram",
@@ -277,20 +292,80 @@ def bucketed_scatter_combine(program: "VertexProgram",
     """
     p = program
     partials = []
-    for b, (cap_b, max_deg_b) in enumerate(zip(caps, part.bucket_max_deg)):
-        if cap_b <= 0 or max_deg_b <= 0:
-            continue  # statically empty bucket
-        mask_b = state.active_scatter & (part.bucket_id == b)
-        n_b = jnp.sum(mask_b)
+    for b, cap_b, max_deg_b in tiled_buckets(part, caps):
+        with jax.named_scope("gre.scatter"):
+            mask_b, fits = bucket_route(part, state.active_scatter, b, cap_b)
         partials.append(jax.lax.cond(
-            n_b <= cap_b,
+            fits,
             lambda m, c=cap_b, d=max_deg_b: compact_scatter_combine(
                 program, part, state, num_segments, c, max_deg=d,
                 frontier_mask=m, kernel=kernel),
             lambda m: dense_masked_combine(program, part, state,
                                            num_segments, m),
             mask_b))
-    return functools.reduce(p.monoid.op, partials)
+    with jax.named_scope("gre.combine"):
+        return functools.reduce(p.monoid.op, partials)
+
+
+def tiled_buckets(part: "DevicePartition", caps: Sequence[int]) -> list:
+    """`(b, cap_b, max_deg_b)` of each degree bucket that gathers a
+    tile; a statically empty bucket (no capacity or no out-edges) has
+    none."""
+    return [(b, cap_b, max_deg_b) for b, (cap_b, max_deg_b)
+            in enumerate(zip(caps, part.bucket_max_deg))
+            if cap_b > 0 and max_deg_b > 0]
+
+
+def bucket_route(part: "DevicePartition", active: jnp.ndarray, b: int,
+                 cap_b: int) -> tuple:
+    """`(mask_b, fits)`: bucket b's live members, and whether they fit
+    its capacity — the predicate that picks its tile over its
+    bucket-restricted dense scan."""
+    mask_b = active & (part.bucket_id == b)
+    return mask_b, jnp.sum(mask_b) <= cap_b
+
+
+def fits_capacity(plan: "FrontierPlan", active: jnp.ndarray) -> jnp.ndarray:
+    """Whether the live frontier fits the plan's whole compacted capacity
+    — the predicate that picks the compacted route over the dense scan
+    (the density crossover and the whole-frontier overflow guard)."""
+    kind, caps = plan
+    return jnp.sum(active) <= (caps if kind == "flat" else sum(caps))
+
+
+def superstep_counts(plan: "FrontierPlan", part: "DevicePartition",
+                     active: jnp.ndarray) -> jnp.ndarray:
+    """One row of `EngineState.counters` for the superstep that scatters
+    from `active` under `plan` (int32 `[3]`, columns `COUNTERS`):
+
+      active_vertices   live frontier slots;
+      active_out_edges  their out-degrees summed (CSR `indptr`): the edges
+                        the superstep must read whatever route runs it;
+      edges_scanned     the edges the chosen route reads — `E_pad` for the
+                        dense scan; for the compacted route `cap * max_deg`
+                        (flat) or, per bucket, `cap_b * max_deg_b` when its
+                        members fit and `E_pad` for its restricted dense
+                        scan when they overflow.
+
+    The route comes from the same predicates the scatter stage branches
+    on (`fits_capacity`, `bucket_route`).  Each count stays below 2**31
+    while (buckets + 1) * E_pad does.
+    """
+    e_pad = part.src.shape[0]
+    needed = jnp.sum(jnp.where(active, jnp.diff(part.csr_indptr), 0))
+    if plan.kind == "dense":
+        scanned = jnp.int32(e_pad)
+    else:
+        if plan.kind == "flat":
+            tiles = plan.caps * part.csr_max_deg
+        else:
+            tiles = sum(
+                jnp.where(bucket_route(part, active, b, cap_b)[1],
+                          cap_b * max_deg_b, e_pad)
+                for b, cap_b, max_deg_b in tiled_buckets(part, plan.caps))
+        scanned = jnp.where(fits_capacity(plan, active), tiles, e_pad)
+    return jnp.stack([jnp.sum(active, dtype=jnp.int32), needed,
+                      scanned]).astype(jnp.int32)
 
 
 def bucketed_tile_occupancy(part: "DevicePartition", active: jnp.ndarray,
@@ -311,9 +386,7 @@ def bucketed_tile_occupancy(part: "DevicePartition", active: jnp.ndarray,
     from repro.kernels.segment_combine import dynamic_block_table
     nseg = num_segments or part.num_slots
     visited = total = 0
-    for b, (cap_b, max_deg_b) in enumerate(zip(caps, part.bucket_max_deg)):
-        if cap_b <= 0 or max_deg_b <= 0:
-            continue
+    for b, cap_b, max_deg_b in tiled_buckets(part, caps):
         mask_b = active & (part.bucket_id == b)
         frontier = compact_indices(mask_b, cap_b, part.num_slots)
         eid, valid = gather_frontier_edge_tile(part, frontier, cap_b,
@@ -343,18 +416,18 @@ def frontier_scatter_combine(program: "VertexProgram",
     plan's combine-kernel stage, threaded into the tile combines.
     """
     kind, caps = plan
-    n_active = jnp.sum(state.active_scatter)
+    with jax.named_scope("gre.scatter"):
+        compact = fits_capacity(plan, state.active_scatter)
     if kind == "flat":
         return jax.lax.cond(
-            n_active <= caps,
+            compact,
             lambda _: compact_scatter_combine(program, part, state,
                                               num_segments, caps,
                                               kernel=kernel),
             lambda _: dense_fn(),
             operand=None)
-    total_cap = sum(caps)
     return jax.lax.cond(
-        n_active <= total_cap,
+        compact,
         lambda _: bucketed_scatter_combine(program, part, state,
                                            num_segments, caps,
                                            kernel=kernel),

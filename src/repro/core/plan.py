@@ -302,7 +302,17 @@ def execute_plan(engine: "GREEngine", part: "DevicePartition",
     has yet to push — an empty frontier with either set is not
     quiescence.  (The landed/local slots never need counting: merge
     consumes them before the predicate runs.)
+
+    A state carrying `counters` (`GREEngine.init_state(counters=rows)`)
+    gets row `step` written at the top of each phase that runs, from the
+    refreshed frontier and the plan's frontier resolution
+    (`frontier.superstep_counts`); a state without them compiles no
+    counting at all.
     """
+    if state.counters is not None and state.counters.shape[0] < max_steps:
+        raise ValueError(f"counters hold {state.counters.shape[0]} rows; a "
+                         f"run of up to {max_steps} supersteps needs that "
+                         f"many")
     globalize = any_active or (lambda local: local)
     pending = getattr(exchange, "carry_pending",
                       lambda carry: jnp.zeros((), dtype=bool))
@@ -313,6 +323,13 @@ def execute_plan(engine: "GREEngine", part: "DevicePartition",
 
     def phase(s, carry):
         s = exchange.refresh(s)
+        if s.counters is not None:
+            from repro.core.frontier import superstep_counts
+            with jax.named_scope("gre.scatter"):
+                row = superstep_counts(engine.make_plan().frontier(part),
+                                       part, s.active_scatter)
+                s = dataclasses.replace(
+                    s, counters=s.counters.at[s.step].set(row))
         return s, exchange.local_phase(engine, part, s, carry)
 
     def phase_if(go, s, carry):

@@ -7,6 +7,8 @@ the chip (block layouts, VMEM/SMEM budgets, partitioning) fails here.
 Nothing runs.  The topology is described inside a fixture, never at import
 time (only one process at a time may load the TPU library).
 """
+import re
+
 import numpy as np
 import pytest
 
@@ -127,3 +129,42 @@ def test_refresh_exchange_compiles_without_sort(topo):
                    s((k, slots), jnp.bool_)).compile().as_text()
     assert "all-to-all" in hlo
     assert " sort(" not in hlo
+
+
+# what the loop itself runs outside the engine's scopes: the keep-going
+# predicate (step < max_steps, any active), the while and cond shells and
+# the conversion of a cond's predicate to its branch index
+LOOP_CONTROL = re.compile(
+    r"jit\(run\)(/(while|body|cond|branch_\d+_fun))*"
+    r"/(and|lt|reduce_or|convert_element_type|cond|while)")
+
+
+@pytest.mark.parametrize("name,frontier,counters", [
+    ("pagerank", "auto", 0), ("cc", "dense", 0), ("cc", "auto", 0),
+    ("cc", "auto", 64)])
+def test_every_engine_op_falls_under_a_scope(one_chip, name, frontier,
+                                             counters):
+    """Each op of the compiled `GREEngine.run` carries `gre.scatter`,
+    `gre.combine` or `gre.apply` in its name stack (the metadata a profile
+    reads), except the loop's own control.  Ops the compiler makes with no
+    `jit(run)` name stack (loop-carry copies, reducer regions, the gather's
+    index clamp) are left out."""
+    g = rmat_edges(scale=9, edge_factor=8, seed=1).dedup()
+    part = DevicePartition.from_graph(g)
+    eng = GREEngine(getattr(algorithms, f"{name}_program")(),
+                    frontier=frontier)
+    state = eng.init_state(part, counters=counters)
+    hlo = GREEngine.run.lower(eng, _abstract(part, one_chip),
+                              _abstract(state, one_chip),
+                              64).compile().as_text()
+    scopes, outside = set(), set()
+    for op_name in re.findall(r'op_name="([^"]*)"', hlo):
+        if not op_name.startswith("jit(run)/"):
+            continue
+        scope = re.findall(r"/(gre\.\w+)", op_name)
+        if scope:
+            scopes.add(scope[-1])
+        elif not LOOP_CONTROL.fullmatch(op_name):
+            outside.add(op_name)
+    assert not outside
+    assert scopes == {"gre.scatter", "gre.combine", "gre.apply"}
