@@ -40,7 +40,7 @@ def run(scale: int = 10, edge_factor: int = 8, d_feat: int = 64,
     rng = np.random.default_rng(0)
     h = jnp.asarray(rng.normal(size=(g.num_vertices, d_feat)), jnp.float32)
     program = gnn_aggregate_program(d_feat)
-    paths = [("xla", GREEngine(program))]
+    paths = [("xla", GREEngine(program, use_pallas=False))]
     if pallas:
         paths.append(("pallas", GREEngine(program, use_pallas=True)))
     out = {}

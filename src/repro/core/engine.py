@@ -90,8 +90,9 @@ class DevicePartition:
                                               metadata=dict(static=True))
     # Ingress-time Pallas block schedule of the dst-sorted `dst` column over
     # `num_slots` (kernels.segment_combine.build_block_table) — what the
-    # dense scan's `use_pallas` route visits.  None on partitions whose
-    # edges are not dst-sorted; that route then refuses to run.
+    # dense scan's Pallas route visits (`resolve_combine_route`).  None on
+    # partitions whose edges are not dst-sorted; that route then refuses
+    # to run when forced and is never resolved to.
     combine_table: Optional[jnp.ndarray] = None   # [2, G] int32
 
     @staticmethod
@@ -324,6 +325,32 @@ class DevicePartition:
         return new, report
 
 
+def resolve_combine_route(program: VertexProgram, part: DevicePartition,
+                          num_segments: int) -> str:
+    """The dense scan's default combine route for one call: "pallas" where
+    the Pallas block kernel applies, else "xla".
+
+    The kernel applies to dst-sorted edges whose ingress-time
+    `combine_table` spans this call's segment space (its length is
+    `table_length(E, num_segments)`), a scalar float32 payload and a sum,
+    min or max monoid.  "pallas" runs the kernel where the program is
+    lowered for a TPU; every other platform lowers the XLA scatter-reduce
+    (`segment_combine(use_pallas=None)`).  Everything else — the exchange
+    backends' agent and split-tile segment spaces, D-wide payloads,
+    integer payloads — resolves to "xla".
+    """
+    from repro.kernels.segment_combine import table_length
+    table = part.combine_table
+    fits = (part.edges_sorted_by_dst and table is not None
+            and part.dst is not None
+            and table.shape[-1] == table_length(part.dst.shape[-1],
+                                                num_segments))
+    scalar = (program.payload_shape == ()
+              and jnp.dtype(program.msg_dtype) == jnp.float32)
+    kernel_op = program.monoid.name in ("sum", "min", "max")
+    return "pallas" if fits and scalar and kernel_op else "xla"
+
+
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass
 class EngineState:
@@ -379,6 +406,12 @@ class GREEngine:
                   benchmark ablation showing why bucketing exists).
       "dense"   — the original every-edge masked scan.
 
+    `use_pallas` picks the combine kernel.  Unset (None), the dense scan
+    resolves its route per call (`resolve_combine_route`): the Pallas
+    block kernel where it applies and the program is lowered for a TPU,
+    the XLA scatter-reduce otherwise; the compacted tiles keep XLA.
+    True or False forces one route on both.
+
     Engines in `dense_frontier` mode (iterative programs like PageRank,
     where every vertex stays active) and partitions without a CSR layout
     always take the dense path.  Level-synchronous iterative programs that
@@ -390,7 +423,8 @@ class GREEngine:
 
     FRONTIERS = ("auto", "dense", "compact", "flat")
 
-    def __init__(self, program: VertexProgram, use_pallas: bool = False,
+    def __init__(self, program: VertexProgram,
+                 use_pallas: Optional[bool] = None,
                  dense_frontier: Optional[bool] = None,
                  frontier: str = "auto", frontier_cap: Optional[int] = None,
                  dynamic_table: bool = True, plan=None, plan_cache=None):
@@ -514,7 +548,7 @@ class GREEngine:
             strategy=self.frontier, frontier_cap=self.frontier_cap,
             dense_frontier=self.dense_frontier, phases=phases,
             staleness=staleness,
-            kernel=KernelPlan(use_pallas=self.use_pallas,
+            kernel=KernelPlan(use_pallas=bool(self.use_pallas),
                               dynamic_table=self.dynamic_table))
 
     def _frontier_plan(self, part: DevicePartition):
@@ -759,14 +793,25 @@ class GREEngine:
                 msgs = jnp.where(live, msgs.astype(p.msg_dtype),
                                  p.monoid.identity)
         nseg = num_segments or part.num_slots
-        table = None
-        if self.use_pallas:
+        table, use_pallas = None, False
+        if self.combine_route(part, nseg) == "pallas":
+            # unset (None): the kernel where the program is lowered for a TPU
             table = self._combine_table(part, nseg)
+            use_pallas = self.use_pallas
         with jax.named_scope("gre.combine"):
             return segment_combine(
                 msgs, part.dst, nseg, p.monoid,
                 indices_are_sorted=part.edges_sorted_by_dst,
-                use_pallas=self.use_pallas, table=table)
+                use_pallas=use_pallas, table=table)
+
+    def combine_route(self, part: DevicePartition,
+                      num_segments: Optional[int] = None) -> str:
+        """The dense scan's combine route, "pallas" or "xla": forced by an
+        explicit `use_pallas`, else `resolve_combine_route`."""
+        if self.use_pallas is not None:
+            return "pallas" if self.use_pallas else "xla"
+        return resolve_combine_route(self.program, part,
+                                     num_segments or part.num_slots)
 
     @staticmethod
     def _combine_table(part: DevicePartition, num_segments: int):

@@ -341,7 +341,8 @@ def execute_plan(engine: "GREEngine", part: "DevicePartition",
         go = keep_going(s, carry)
         return phase_if(go, s, carry) + (go,)
 
-    carry_init = exchange.carry_init(engine, part)
+    with jax.named_scope("gre.combine"):   # the ⊕ identity accumulator
+        carry_init = exchange.carry_init(engine, part)
     go0 = keep_going(state, carry_init)
     carry0 = phase_if(go0, state, carry_init) + (go0,)
     final, _, _ = jax.lax.while_loop(lambda c: c[2], body, carry0)

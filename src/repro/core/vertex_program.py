@@ -89,7 +89,8 @@ MONOIDS: Dict[str, Monoid] = {
 
 def segment_combine(msgs: jnp.ndarray, dst: jnp.ndarray, num_segments: int,
                     monoid: Monoid, indices_are_sorted: bool = False,
-                    use_pallas: bool = False, table=None) -> jnp.ndarray:
+                    use_pallas: Optional[bool] = False,
+                    table=None) -> jnp.ndarray:
     """One-sided combine of active messages at their destinations.
 
     This is the Scatter-Combine hot path.  The XLA path lowers to a fused
@@ -97,12 +98,21 @@ def segment_combine(msgs: jnp.ndarray, dst: jnp.ndarray, num_segments: int,
     VMEM blocks and turns the irregular reduction into block-local one-hot
     MXU matmuls (sum) or masked VPU reductions (min/max), visiting the
     blocks that `table` (the ingress-time block schedule of `dst`) lists.
+    `use_pallas=None` takes the Pallas path where the program is lowered
+    for a TPU and the XLA path on every other platform.
     """
-    if use_pallas:
+    def xla(m, d, t):
+        return monoid.segment_reduce(m, d, num_segments, indices_are_sorted)
+
+    def pallas(m, d, t):
         from repro.kernels import ops as kernel_ops
-        return kernel_ops.segment_combine(msgs, dst, num_segments,
-                                          monoid.name, table=table)
-    return monoid.segment_reduce(msgs, dst, num_segments, indices_are_sorted)
+        return kernel_ops.segment_combine(m, d, num_segments, monoid.name,
+                                          table=t)
+
+    if use_pallas is None:
+        return jax.lax.platform_dependent(msgs, dst, table, default=xla,
+                                          tpu=pallas)
+    return (pallas if use_pallas else xla)(msgs, dst, table)
 
 
 @dataclasses.dataclass(frozen=True)

@@ -20,7 +20,8 @@ def segment_combine(msgs: jnp.ndarray, dst: jnp.ndarray, num_segments: int,
                     op: str = "sum", table: Optional[jnp.ndarray] = None,
                     block_e: int = BLOCK_E,
                     block_v: int = BLOCK_V) -> jnp.ndarray:
-    """Scatter-combine ⊕ along dst-sorted edges.
+    """Scatter-combine ⊕ along dst-sorted edges: msgs [E] or [E, D] to
+    [num_segments] or [num_segments, D].
 
     `table` is the ingress-time block schedule of the (dst-sorted) `dst`
     column (segment_combine.build_block_table).  Without one, `dst` must be
@@ -29,8 +30,6 @@ def segment_combine(msgs: jnp.ndarray, dst: jnp.ndarray, num_segments: int,
     without a table is an error: the kernel cannot schedule blocks it
     cannot see.
     """
-    squeeze = msgs.ndim == 1
-    m2 = msgs[:, None] if squeeze else msgs
     if table is None:
         if isinstance(dst, jax.core.Tracer):
             raise ValueError(
@@ -40,14 +39,13 @@ def segment_combine(msgs: jnp.ndarray, dst: jnp.ndarray, num_segments: int,
         dst_np = np.asarray(dst)
         order = np.argsort(dst_np, kind="stable")
         dst_np = dst_np[order]
-        m2 = m2[jnp.asarray(order)]
+        msgs = msgs[jnp.asarray(order)]
         dst = jnp.asarray(dst_np)
         table = jnp.asarray(build_block_table(dst_np, num_segments,
                                               block_e, block_v))
-    out = segment_combine_pallas(m2, dst, table, num_segments, op,
+    out = segment_combine_pallas(msgs, dst, table, num_segments, op,
                                  block_e=block_e, block_v=block_v)
-    out = out.astype(msgs.dtype)
-    return out[:, 0] if squeeze else out
+    return out.astype(msgs.dtype)
 
 
 def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,

@@ -17,9 +17,11 @@ one-hot reductions** over dst-sorted edges:
     time).  Consecutive visits of one dst block accumulate into the same
     VMEM output block, which is written back when the dst block changes.
 
-Edges run along the 128-wide lane axis: messages are carried transposed as
-`[D, E]` and destinations as `[1, E]`, so a scalar payload (D=1) is one
-dense lane row instead of an `[E, 1]` column padded 128-fold in HBM.
+Edges run along the 128-wide lane axis: D-wide messages are carried
+transposed as `[D, E]`, while scalar messages and destinations stay the
+`[E]` columns they are, so no operand is padded, transposed or relaid
+out per call; the kernel masks the lanes of the last edge block that run
+past E.
 
 THREE functions produce the same `[2, G]` int32 schedule (row 0: dst block,
 row 1: edge block; see docs/kernels.md):
@@ -45,12 +47,12 @@ each aliasing the previous call's output, and a dst block cut by a slice
 boundary resumes from that output.
 
 VMEM per grid step (defaults BE=1024, BV=256, f32): the double-buffered
-blocks `2·(8·BE + D₈·BE + 2·D₈·BV)` words (row counts pad to 8 sublanes:
-D₈ = D rounded up to 8; the output block and the resumed-output input)
-plus the `[BV, BE]` one-hot and its masked copy, 2·BV·BE words — about
-2.2 MiB at D=1 and 2.8 MiB at D=64, inside the 16 MiB default scoped
-VMEM.  The min/max body reduces one payload lane at a time, so no
-temporary grows with D.
+blocks `2·(BE + D₈·BE + 2·D₈·BV)` words (row counts pad to 8 sublanes:
+D₈ = D rounded up to 8, a scalar payload's block is 1-D; the output
+block and the resumed-output input) plus the `[BV, BE]` one-hot and its
+masked copy, 2·BV·BE words — about 2.1 MiB at D=1 and 2.8 MiB at D=64,
+inside the 16 MiB default scoped VMEM.  The min/max body reduces one
+payload lane at a time, so no temporary grows with D.
 """
 from __future__ import annotations
 
@@ -79,7 +81,7 @@ MAX_VISITS = 32768
 
 
 def _kernel(sched_ref, dst_ref, msgs_ref, prev_ref, out_ref, *, op: str,
-            block_v: int, n_edge_blocks: int):
+            block_v: int, n_edge_blocks: int, num_edges: int):
     g = pl.program_id(0)
     vb = sched_ref[0, g]
     eb = sched_ref[1, g]
@@ -96,13 +98,26 @@ def _kernel(sched_ref, dst_ref, msgs_ref, prev_ref, out_ref, *, op: str,
 
     @pl.when(eb < n_edge_blocks)
     def _accumulate():
-        dst = dst_ref[...]                                   # [1, BE]
+        block_e = dst_ref.shape[0]
+        dst = dst_ref[...].reshape(1, block_e)               # [1, BE]
+        ragged = num_edges % block_e != 0
+        if ragged:
+            # the last edge block runs past E: its lanes there hold
+            # whatever the buffer held, so they hit no row and add nothing
+            lane_id = jax.lax.broadcasted_iota(jnp.int32, (1, block_e), 1)
+            real = lane_id < num_edges - eb * block_e
+            dst = jnp.where(real, dst, -1)
         rows = jax.lax.broadcasted_iota(
-            jnp.int32, (block_v, dst.shape[1]), 0) + vb * block_v
+            jnp.int32, (block_v, block_e), 0) + vb * block_v
         hit = rows == dst                                    # [BV, BE]
+        scalar = msgs_ref.ndim == 1                          # [BE] or [D, BE]
         if op == "sum":
+            msgs = (msgs_ref[...].reshape(1, block_e) if scalar
+                    else msgs_ref[...])
+            if ragged:
+                msgs = jnp.where(real, msgs, 0.0)
             out_ref[...] += jax.lax.dot_general(
-                msgs_ref[...], hit.astype(jnp.float32),
+                msgs, hit.astype(jnp.float32),
                 (((1,), (1,)), ((), ())),
                 precision=jax.lax.Precision.HIGHEST,
                 preferred_element_type=jnp.float32)         # [D, BV] on MXU
@@ -111,14 +126,15 @@ def _kernel(sched_ref, dst_ref, msgs_ref, prev_ref, out_ref, *, op: str,
         fold = jnp.minimum if op == "min" else jnp.maximum
 
         def lane(d, carry):
-            msg = msgs_ref[pl.ds(d, 1), :]                   # [1, BE]
+            msg = (msgs_ref[...].reshape(1, block_e) if scalar
+                   else msgs_ref[pl.ds(d, 1), :])            # [1, BE]
             col = reduce(jnp.where(hit, msg, _OP_IDENTITY[op]), axis=1,
                          keepdims=True)                      # [BV, 1]
             row = jnp.transpose(jnp.broadcast_to(col, (block_v, 128)))[:1]
             out_ref[pl.ds(d, 1), :] = fold(out_ref[pl.ds(d, 1), :], row)
             return carry
 
-        jax.lax.fori_loop(0, msgs_ref.shape[0], lane, 0)
+        jax.lax.fori_loop(0, out_ref.shape[0], lane, 0)   # D lanes
 
 
 def _schedule(xp, dst_sorted, num_segments: int, block_e: int,
@@ -264,25 +280,28 @@ def tile_segment_combine_pallas(msgs: jnp.ndarray, dst: jnp.ndarray,
 
 def _combine_call(sched, dst, msgs, prev, *, op: str, block_e: int,
                   block_v: int, n_edge_blocks: int, interpret: bool):
-    """One kernel call over a `[3, C]` schedule slice; `prev` ([D, V_pad])
-    is aliased to the output, so dst blocks this slice never visits keep
+    """One kernel call over a `[3, C]` schedule slice; `dst` is `[E]`,
+    `msgs` `[E]` (scalar payload) or `[D, E]`; `prev` ([D, V_pad]) is
+    aliased to the output, so dst blocks this slice never visits keep
     their values."""
-    d = msgs.shape[0]
+    d = prev.shape[0]
 
     def eblock(g, s):
-        return 0, jnp.minimum(s[1, g], n_edge_blocks - 1)
+        return (jnp.minimum(s[1, g], n_edge_blocks - 1),)
 
     def vblock(g, s):
         return 0, s[0, g]
 
+    msgs_spec = (pl.BlockSpec((block_e,), eblock) if msgs.ndim == 1 else
+                 pl.BlockSpec((d, block_e), lambda g, s: (0,) + eblock(g, s)))
     return pl.pallas_call(
         functools.partial(_kernel, op=op, block_v=block_v,
-                          n_edge_blocks=n_edge_blocks),
+                          n_edge_blocks=n_edge_blocks,
+                          num_edges=dst.shape[0]),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(sched.shape[1],),
-            in_specs=[pl.BlockSpec((1, block_e), eblock),
-                      pl.BlockSpec((d, block_e), eblock),
+            in_specs=[pl.BlockSpec((block_e,), eblock), msgs_spec,
                       pl.BlockSpec((d, block_v), vblock)],
             out_specs=pl.BlockSpec((d, block_v), vblock),
         ),
@@ -298,18 +317,29 @@ def segment_combine_pallas(msgs: jnp.ndarray, dst: jnp.ndarray,
                            table: jnp.ndarray, num_segments: int,
                            op: str = "sum", block_e: int = BLOCK_E,
                            block_v: int = BLOCK_V) -> jnp.ndarray:
-    """msgs [E, D] (dst-sorted), dst [E] int32, table `[2, G]` from any of
-    the schedule functions above.  Returns [num_segments, D] float32.
+    """msgs [E] or [E, D] (dst-sorted), dst [E] int32, table `[2, G]` from
+    any of the schedule functions above.  Returns [num_segments] or
+    [num_segments, D] float32.
+
+    Operands are not padded: `dst` and a scalar payload enter the kernel
+    as the `[E]` columns they are, and a D-wide payload as its `[D, E]`
+    transpose.  The last edge block may run past E; the kernel masks
+    those lanes.
 
     Compiled by Mosaic when lowered for a TPU and run by the Pallas
     interpreter on CPU (`repro.kernels.on_backend`)."""
-    e, d_feat = msgs.shape
-    n_e = max(1, -(-e // block_e))
+    e = msgs.shape[0]
+    scalar = msgs.ndim == 1
+    msgs = msgs.astype(jnp.float32)
+    msgs_t = msgs if scalar else msgs.T
+    dst = dst.astype(jnp.int32)
+    if e < block_e:   # a block wider than the whole column: pad, < BE lanes
+        msgs_t = jnp.pad(msgs_t, ((0, 0),) * (msgs_t.ndim - 1) +
+                         ((0, block_e - e),))
+        dst = jnp.pad(dst, (0, block_e - e), constant_values=_DST_SENTINEL)
+    d_feat = 1 if scalar else msgs_t.shape[0]
+    n_e = -(-dst.shape[0] // block_e)
     n_v = -(-num_segments // block_v)
-    pad = n_e * block_e - e
-    msgs_t = jnp.pad(msgs.astype(jnp.float32), ((0, pad), (0, 0))).T
-    dst_row = jnp.pad(dst.astype(jnp.int32), (0, pad),
-                      constant_values=_DST_SENTINEL)[None, :]
     vb, eb = table[0].astype(jnp.int32), table[1].astype(jnp.int32)
     g = vb.shape[0]
     chunk = min(g, MAX_VISITS)
@@ -326,8 +356,8 @@ def segment_combine_pallas(msgs: jnp.ndarray, dst: jnp.ndarray,
                              block_v=block_v, n_edge_blocks=n_e)
 
     def run(c, acc):
-        return on_backend(call, sched[:, c], dst_row, msgs_t, acc)
+        return on_backend(call, sched[:, c], dst, msgs_t, acc)
 
     out = jax.lax.fori_loop(0, n_chunks, run, out) if n_chunks > 1 \
         else run(0, out)
-    return out[:, :num_segments].T
+    return out[0, :num_segments] if scalar else out[:, :num_segments].T

@@ -1,4 +1,7 @@
 """Single-shard Scatter-Combine engine vs exact oracles (networkx/numpy)."""
+import dataclasses
+import re
+
 import networkx as nx
 import numpy as np
 import pytest
@@ -6,8 +9,12 @@ import pytest
 import jax.numpy as jnp
 
 from repro.core import algorithms
-from repro.core.engine import DevicePartition, GREEngine
+from repro.core.engine import (DevicePartition, GREEngine,
+                               resolve_combine_route)
+from repro.core.plan import XLA_KERNEL
 from repro.graph.generators import ring_graph, rmat_edges
+from repro.graph.structures import EdgeDelta
+from repro.kernels.segment_combine import BLOCK_V
 
 
 @pytest.fixture(scope="module")
@@ -121,7 +128,7 @@ def test_engine_with_pallas_kernel_matches_xla(graph):
     """The Pallas segment_combine kernel (interpret mode) slots into the
     engine via use_pallas and reproduces the XLA path exactly."""
     part = DevicePartition.from_graph(graph)
-    eng_x = GREEngine(algorithms.pagerank_program())
+    eng_x = GREEngine(algorithms.pagerank_program(), use_pallas=False)
     eng_p = GREEngine(algorithms.pagerank_program(), use_pallas=True)
     st_x = eng_x.init_state(part)
     st_p = eng_p.init_state(part)
@@ -131,3 +138,82 @@ def test_engine_with_pallas_kernel_matches_xla(graph):
     np.testing.assert_allclose(np.asarray(st_x.vertex_data),
                                np.asarray(st_p.vertex_data),
                                rtol=1e-5, atol=1e-5)
+
+
+def _delta_part(graph):
+    """The graph's partition after a delta that adds two edges."""
+    part = DevicePartition.from_graph(graph, edge_slack=8)
+    delta = EdgeDelta(add_src=[0, 1], add_dst=[2, 3],
+                      add_props={"weight": np.ones(2, np.float32)})
+    return part.apply_edge_delta(delta)[0]
+
+
+# program, partition, segment space past the slots, source, route
+ROUTE_CASES = {
+    "pagerank": (algorithms.pagerank_program, DevicePartition.from_graph,
+                 0, None, "pallas"),
+    "cc": (algorithms.cc_program, DevicePartition.from_graph, 0, None,
+           "pallas"),
+    "max-monoid": (lambda: dataclasses.replace(
+        algorithms.sssp_program(), monoid=algorithms.MONOIDS["max"]),
+        DevicePartition.from_graph, 0, 0, "pallas"),
+    "after-delta": (algorithms.pagerank_program, _delta_part, 0, None,
+                    "pallas"),
+    "d16-lanes": (lambda: algorithms.bfs_program(num_sources=16),
+                  DevicePartition.from_graph, 0, list(range(16)), "xla"),
+    "int-payload": (lambda: dataclasses.replace(
+        algorithms.degree_program(), msg_dtype=jnp.int32),
+        DevicePartition.from_graph, 0, None, "xla"),
+    "unsorted": (algorithms.pagerank_program,
+                 lambda g: DevicePartition.from_graph(g, sort_by_dst=False),
+                 0, None, "xla"),
+    "other-segment-space": (algorithms.pagerank_program,
+                            DevicePartition.from_graph, 2 * BLOCK_V, None,
+                            "xla"),
+}
+
+
+@pytest.mark.parametrize("case", list(ROUTE_CASES))
+def test_dense_combine_route_resolution(graph, case):
+    """The default engine's dense combine takes the Pallas kernel only for
+    dst-sorted edges whose block table spans the call's segment space, a
+    scalar float32 payload and a sum/min/max monoid; everything else
+    resolves to XLA without raising.  The compacted tiles keep XLA, and an
+    explicit `use_pallas` forces its route.  On the CPU both routes lower
+    the XLA scatter-reduce, so the default engine's combine equals the
+    forced XLA engine's bitwise."""
+    make_program, make_part, extra, source, want = ROUTE_CASES[case]
+    program, part = make_program(), make_part(graph)
+    nseg = part.num_slots + extra
+    assert resolve_combine_route(program, part, nseg) == want
+    eng = GREEngine(program)
+    assert eng.combine_route(part, nseg) == want
+    assert eng.make_plan().kernel == XLA_KERNEL
+    assert GREEngine(program, use_pallas=True).combine_route(
+        part, nseg) == "pallas"
+    assert GREEngine(program, use_pallas=False).combine_route(
+        part, nseg) == "xla"
+    xla = GREEngine(program, use_pallas=False)
+    got, ref = (e.dense_scatter_combine(part, e.init_state(part, source),
+                                        nseg) for e in (eng, xla))
+    assert got.shape == (nseg,) + tuple(program.payload_shape)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
+
+
+@pytest.mark.parametrize("name", ["pagerank", "cc"])
+def test_default_engine_lowers_no_kernel_on_cpu(graph, name):
+    """Lowered for the CPU, the default engine's run holds the forced XLA
+    engine's instructions and nothing more (the kernel is lowered only for
+    a TPU, so no interpreted kernel slows the CPU path); the block table
+    enters as one more, unused, parameter."""
+    part = DevicePartition.from_graph(graph)
+    program = getattr(algorithms, f"{name}_program")()
+    ops = []
+    for use_pallas in (None, False):
+        eng = GREEngine(program, use_pallas=use_pallas)
+        hlo = GREEngine.run.lower(eng, part, eng.init_state(part),
+                                  30).compile().as_text()
+        ops.append(sorted(re.findall(r"= \S+ ([\w-]+)\(", hlo)))
+    assert ops[0].count("parameter") == ops[1].count("parameter") + 1
+    strip = lambda o: [op for op in o if op != "parameter"]
+    assert strip(ops[0]) == strip(ops[1])

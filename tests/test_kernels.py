@@ -7,8 +7,10 @@ from hypothesis import given, settings, strategies as st
 
 import jax.numpy as jnp
 
+from repro.core.vertex_program import MONOIDS
 from repro.kernels import ops, ref
-from repro.kernels.segment_combine import build_block_table, segment_combine_pallas
+from repro.kernels.segment_combine import (BLOCK_E, build_block_table,
+                                           segment_combine_pallas)
 
 RNG = np.random.default_rng(0)
 
@@ -47,6 +49,33 @@ def test_segment_combine_hypothesis(e, v, d, seed):
     out = ops.segment_combine(msgs, jnp.asarray(dst), v, "sum")
     want = ref.segment_combine_ref(msgs, jnp.asarray(dst), v, "sum")
     np.testing.assert_allclose(out, want, rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("masked", [False, True])
+@pytest.mark.parametrize("op", ["sum", "min", "max"])
+@pytest.mark.parametrize("e", [3 * BLOCK_E + 489, 2 * BLOCK_E + 1])
+def test_segment_combine_ragged_scalar(e, op, masked):
+    """Scalar messages over an E that is not a multiple of BLOCK_E enter
+    the kernel unpadded, and the last edge block's lanes past E (NaN in the
+    interpreter) add nothing: the combine equals the XLA scatter-reduce,
+    exactly for min and max.  `masked` replaces half the messages by the
+    identity, as the dense scan does for inactive sources."""
+    v = 700
+    rng = np.random.default_rng(e)
+    dst = np.sort(rng.integers(0, v + 1, e)).astype(np.int32)
+    msgs = rng.normal(size=e).astype(np.float32)
+    if masked:
+        msgs = np.where(rng.random(e) < 0.5, msgs, MONOIDS[op].identity)
+    msgs, dst_j = jnp.asarray(msgs, jnp.float32), jnp.asarray(dst)
+    got = ops.segment_combine(msgs, dst_j, v + 1, op,
+                              table=jnp.asarray(build_block_table(dst,
+                                                                  v + 1)))
+    want = MONOIDS[op].segment_reduce(msgs, dst_j, v + 1, True)
+    assert got.shape == (v + 1,)
+    if op == "sum":
+        np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+    else:
+        np.testing.assert_array_equal(got, want)
 
 
 def test_block_table_covers_all_edges():

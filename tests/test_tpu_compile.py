@@ -7,6 +7,7 @@ the chip (block layouts, VMEM/SMEM budgets, partitioning) fails here.
 Nothing runs.  The topology is described inside a fixture, never at import
 time (only one process at a time may load the TPU library).
 """
+import dataclasses
 import re
 
 import numpy as np
@@ -23,6 +24,11 @@ from repro.core.engine import DevicePartition, GREEngine
 from repro.core.partition import hash_partition
 from repro.graph.generators import rmat_edges
 from repro.kernels import segment_combine as sc
+
+# E of the benchmark's cells (chipbench/configs): R-MAT scale 22 for
+# PageRank, its symmetrized copy for CC, both over 2**22 vertices
+CELL_EDGES = {"pagerank": 65_242_601, "cc": 128_303_672}
+CELL_VERTICES = 1 << 22
 
 
 @pytest.fixture(scope="module")
@@ -168,3 +174,77 @@ def test_every_engine_op_falls_under_a_scope(one_chip, name, frontier,
             outside.add(op_name)
     assert not outside
     assert scopes == {"gre.scatter", "gre.combine", "gre.apply"}
+
+
+def _cell_shapes(name, sharding):
+    """`(program, part, state)` with the shapes of the benchmark cell that
+    runs `name` (abstract: nothing is allocated).  The statics come from a
+    small graph's partition; the dense plan reads none of them."""
+    e, v = CELL_EDGES[name], CELL_VERTICES
+    g = rmat_edges(scale=9, edge_factor=8, seed=1,
+                   weights=name == "pagerank").dedup()
+    small = DevicePartition.from_graph(g)
+    col = lambda n, dt: jax.ShapeDtypeStruct((n,), dt, sharding=sharding)
+    part = dataclasses.replace(
+        small, src=col(e, jnp.int32), dst=col(e, jnp.int32),
+        edge_mask=col(e, jnp.bool_), num_masters=v, num_slots=v + 1,
+        edge_props={k: col(e, a.dtype) for k, a in small.edge_props.items()},
+        aux={k: col(v, a.dtype) for k, a in small.aux.items()},
+        csr_indptr=col(v + 2, jnp.int32), csr_eidx=col(e, jnp.int32),
+        bucket_id=col(v + 1, jnp.int32),
+        combine_table=jax.ShapeDtypeStruct(
+            (2, sc.table_length(e, v + 1)), jnp.int32, sharding=sharding))
+    program = getattr(algorithms, f"{name}_program")()
+    state = GREEngine(program).init_state(small)
+    slots = {small.num_masters: v, small.num_slots: v + 1}
+    state = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        (slots.get(a.shape[0], a.shape[0]),) + a.shape[1:] if a.ndim
+        else (), a.dtype, sharding=sharding), state)
+    return program, part, state
+
+
+def edge_sized_copies(hlo: str, num_edges: int) -> list:
+    """Instructions of a compiled module that copy an edge column on the
+    combine's operand path: a pad, copy, transpose or fusion under
+    `gre.combine` whose result spans the edges (E up to the next edge
+    block), and any array of rank 2 or more that does (every edge column
+    is 1-D, so such an array is a relayout)."""
+    found = []
+    for line in hlo.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%\S+ = (.+?) ([\w-]+)\(", line)
+        if not m:
+            continue
+        dims = [tuple(map(int, d.split(",")))
+                for d in re.findall(r"\[([\d,]+)\]", m.group(1))]
+        spans = [d for d in dims
+                 if any(num_edges <= n < num_edges + sc.BLOCK_E for n in d)]
+        copy = (m.group(2) in ("pad", "copy", "copy-start", "transpose",
+                               "fusion") and "gre.combine" in line)
+        if spans and (copy or any(len(d) > 1 for d in spans)):
+            found.append(line.strip()[:160])
+    return found
+
+
+@pytest.mark.parametrize("name", ["pagerank", "cc"])
+def test_default_dense_combine_is_the_kernel_without_edge_copies(one_chip,
+                                                                 name):
+    """At the cells' sizes, the default engine's dense combine (PageRank,
+    and CC's masked scan) compiles for the chip to the Pallas kernel under
+    `gre.combine`, with no edge-sized pad, copy or transpose in the loop or
+    hoisted out of it, and with temporaries within 1% of the XLA route's.
+    `use_pallas=False` still forces XLA."""
+    program, part, state = _cell_shapes(name, one_chip)
+    compiled = {}
+    for use_pallas in (None, False):
+        eng = GREEngine(program, frontier="dense", use_pallas=use_pallas)
+        compiled[use_pallas] = GREEngine.run.lower(eng, part, state,
+                                                   64).compile()
+    hlo = compiled[None].as_text()
+    kernels = [line for line in hlo.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line]
+    assert kernels and all("gre.combine" in line for line in kernels)
+    assert edge_sized_copies(hlo, CELL_EDGES[name]) == []
+    assert "tpu_custom_call" not in compiled[False].as_text()
+    temp = {k: c.memory_analysis().temp_size_in_bytes
+            for k, c in compiled.items()}
+    assert temp[None] <= 1.01 * temp[False]
