@@ -1,0 +1,14 @@
+"""refresh_ms: device self milliseconds per superstep under the scope
+`gre.exchange.refresh` (the scatter-agent refresh: the gather of master
+values, the `all_to_all` and the scatter into agent slots), from the
+trace: the scope's self time inside the window, mean over the chips, over
+the supersteps of the window's jobs.  None where the run recorded no such
+scope."""
+
+
+def read(record, cell):
+    scopes = getattr(record, "scopes", None) or {}
+    steps = sum(record.supersteps)
+    if "gre.exchange.refresh" not in scopes or steps == 0:
+        return None
+    return scopes["gre.exchange.refresh"] * 1e3 / steps

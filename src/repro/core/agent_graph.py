@@ -97,6 +97,28 @@ class AgentGraph:
     def sink(self) -> int:
         return self.cap + self.s_pad + self.c_pad
 
+    def counters(self) -> Dict[str, float]:
+        """The sizes §5.1's traffic bound is stated in, read on the host:
+
+          masters             |V|, one master per original vertex
+          scatter_agents      |V_s|, Σ `num_scatter`
+          combiner_agents     |V_c|, Σ `num_combiner`
+          exchange_rows       k·k·(`s_x_pad` + `c_x_pad`): the padded
+                              payload rows the refresh and the flush
+                              `all_to_all`s move over the mesh a superstep
+                              (a sparse frontier's refresh also moves as
+                              many activity flags)
+          replication_factor  (|V| + |V_s| + |V_c|) / |V|, copies a vertex
+        """
+        scatter = int(self.num_scatter.sum())
+        combiner = int(self.num_combiner.sum())
+        return {"masters": self.num_vertices, "scatter_agents": scatter,
+                "combiner_agents": combiner,
+                "exchange_rows": self.k * self.k * (self.s_x_pad
+                                                    + self.c_x_pad),
+                "replication_factor": ((self.num_vertices + scatter + combiner)
+                                       / max(self.num_vertices, 1))}
+
 
 def _pad_to(arr: np.ndarray, n: int, fill) -> np.ndarray:
     out = np.full(n, fill, dtype=arr.dtype if arr.size else np.int64)
@@ -525,13 +547,27 @@ def build_agent_graph(graph, edge_part, k: int,
     (paper §4.2: backward traversal for multi-stage algorithms) while
     keeping the same edge partition and master placement (owners are
     assigned on the FORWARD graph), so forward and backward stages share
-    vertex ownership and results relabel identically stage to stage."""
-    from repro.core.partition_stream import bitset_set, partition_edges
-    from repro.graph.structures import as_chunk_source
+    vertex ownership and results relabel identically stage to stage.
+
+    The build, after any partitioner a name dispatches to, runs inside the
+    host span `gre.ingress.agent_graph` (`repro.spans`)."""
+    from repro.core.partition_stream import partition_edges
+    from repro.spans import span
 
     if isinstance(edge_part, str):
         partitioner = edge_part
         edge_part = partition_edges(graph, k, method=partitioner)
+    with span("gre.ingress.agent_graph"):
+        return _build_agent_graph(graph, edge_part, k, owner, pad_multiple,
+                                  transpose, chunk_size, partitioner)
+
+
+def _build_agent_graph(graph, edge_part, k: int, owner, pad_multiple: int,
+                       transpose: bool, chunk_size: Optional[int],
+                       partitioner: Optional[str]) -> AgentGraph:
+    """`build_agent_graph`'s two passes over a per-edge placement array."""
+    from repro.core.partition_stream import bitset_set
+
     if hasattr(graph, "chunks"):
         source = graph
     else:

@@ -57,6 +57,7 @@ from repro.core.plan import execute_plan, execute_superstep
 from repro.core.vertex_program import VertexProgram
 from repro.dist.sharding import shard_map
 from repro.kernels.segment_combine import build_block_table
+from repro.spans import span
 
 
 def _squeeze0(tree):
@@ -221,8 +222,13 @@ class DistGREEngine:
                              overlap=self.overlap)
 
     # ----------------------------------------------------------- host → device
+    @span("gre.ingress.topology")
     def device_topology(self, ag: AgentGraph):
         """Stacked arrays [k, ...]; shard_map splits row i to device i.
+
+        The host span `gre.ingress.topology` (`repro.spans`) covers the
+        host work and the enqueued copies, not their completion: a caller
+        that times ingress ends it in `block_until_ready`.
 
         With `exchange="pipelined"` or `exchange="async"` every edge scan
         runs on the split tiles (`ShardTopology.tiles`); the canonical part

@@ -38,6 +38,13 @@ partial combines cross device boundaries.  This module isolates that seam:
                    why non-monotone (sum) programs must refuse this
                    backend.
 
+The two Agent-Graph collectives name their device work for a profile:
+`refresh_scatter_agents` runs under `jax.named_scope("gre.exchange.refresh")`
+and `flush_combiners` under `"gre.exchange.flush"` (gathers, `all_to_all`,
+slot scatter or segment fold), beside the engine's `gre.scatter`,
+`gre.combine` and `gre.apply`; the fold of local and flushed partials is
+`gre.combine`.
+
 All backends speak first-class feature-vector payloads: state and message
 arrays are `[slots, *payload_shape]`; scalars are the `payload_shape=()`
 special case.  Backends are plain callables on jnp arrays, usable inside
@@ -145,6 +152,7 @@ def _master_mask(combined: jnp.ndarray, num_masters: int) -> jnp.ndarray:
     return m.reshape(m.shape + (1,) * (combined.ndim - 1))
 
 
+@jax.named_scope("gre.exchange.refresh")
 def refresh_scatter_agents(topo: ShardTopology, scatter_data: jnp.ndarray,
                            active: jnp.ndarray, axes,
                            dense: bool = False):
@@ -174,6 +182,7 @@ def refresh_scatter_agents(topo: ShardTopology, scatter_data: jnp.ndarray,
     return sd, act
 
 
+@jax.named_scope("gre.exchange.flush")
 def flush_combiners(topo: ShardTopology, combined: jnp.ndarray, axes,
                     monoid: Monoid, send_slot: Optional[jnp.ndarray] = None,
                     recv_master: Optional[jnp.ndarray] = None,
@@ -322,13 +331,15 @@ class AgentExchange(_RefreshingExchange):
             flushed = flush_combiners(self.topo, combined_remote, self.axes,
                                       monoid)
             combined_local = engine.scatter_combine(local_part, state)
-            return monoid.op(combined_local, flushed)
+            with jax.named_scope("gre.combine"):
+                return monoid.op(combined_local, flushed)
         combined = engine.scatter_combine(part, state)
         flushed = flush_combiners(self.topo, combined, self.axes, monoid)
         # master slots take direct local + flushed remote contributions
-        local = jnp.where(_master_mask(combined, part.num_masters),
-                          combined, monoid.identity)
-        return monoid.op(local, flushed)
+        with jax.named_scope("gre.combine"):
+            local = jnp.where(_master_mask(combined, part.num_masters),
+                              combined, monoid.identity)
+            return monoid.op(local, flushed)
 
 
 class DenseExchange(_RefreshingExchange):

@@ -35,6 +35,7 @@ from typing import Dict, Optional
 import numpy as np
 
 from repro.graph.structures import as_chunk_source
+from repro.spans import span
 
 HDRF_EPS = 1.0  # balance-term regularizer (Δ-analog of partition.DELTA)
 
@@ -82,6 +83,7 @@ def greedy_state_bytes(num_vertices: int, k: int,
 
 
 # ------------------------------------------------------------------ HDRF
+@span("gre.ingress.hdrf")
 def hdrf_partition(graph, k: int, *, lam: float = 1.0,
                    batch_size: int = 256, seed: int = 0,
                    chunk_size: Optional[int] = None,
@@ -111,6 +113,7 @@ def hdrf_partition(graph, k: int, *, lam: float = 1.0,
     Deterministic for a fixed seed (the tiny rng tie-break is the only
     randomness).  `stats`, when given, is filled with the measured
     `state_bytes`, `replication` (Σ|A(v)|), and `replication_factor`.
+    The whole placement is the host span `gre.ingress.hdrf` (`repro.spans`).
     """
     source = as_chunk_source(graph, chunk_size or (1 << 18))
     V, E = source.num_vertices, source.num_edges

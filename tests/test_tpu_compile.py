@@ -176,6 +176,55 @@ def test_every_engine_op_falls_under_a_scope(one_chip, name, frontier,
     assert scopes == {"gre.scatter", "gre.combine", "gre.apply"}
 
 
+# the same for the shard function of `DistGREEngine.make_run`, where the
+# keep-going predicate is made mesh-global by a pmax (its compare and
+# conversions included), plus the shard's entry and exit (the stacked
+# operands' leading axis dropped and restored) and the broadcasts the
+# partitioner makes of constants, named by their HLO instruction
+SHARD_CONTROL = re.compile(
+    r"jit\(run_shard\)/shard_map((/(while|body|cond|branch_\d+_fun))*"
+    r"/(and|lt|gt|reduce_or|convert_element_type|pmax|cond|while)"
+    r"|/squeeze|/broadcast_in_dim|/broadcast\.\d+)")
+
+
+def test_every_agent_op_falls_under_a_scope(topo):
+    """The 4-device agent PageRank run, compiled for a `v5e:2x2`: every op
+    of the shard function carries an engine scope or one of the exchange's
+    two (`gre.exchange.refresh`, `gre.exchange.flush`), except the loop's
+    control, and every `all-to-all` is under an exchange scope."""
+    from repro.core.partition_stream import hdrf_partition
+    g = rmat_edges(scale=9, edge_factor=8, seed=2).dedup()
+    ag = build_agent_graph(g, hdrf_partition(g, 4), 4)
+    prog = algorithms.pagerank_program()
+    host = DistGREEngine(prog, jax.make_mesh((1,), ("graph",)))
+    topo_arrays = host.device_topology(ag)
+    state = host.init_state(ag)
+    mesh = jax.sharding.Mesh(np.asarray(topo.devices[:4]), ("graph",))
+    rows = NamedSharding(mesh, P("graph"))
+    eng = DistGREEngine(prog, mesh, ("graph",), exchange="agent")
+    hlo = eng.make_run(ag, max_steps=30).lower(
+        _abstract(topo_arrays, rows), _abstract(state, rows)
+    ).compile().as_text()
+    scopes, outside = set(), set()
+    for op_name in re.findall(r'op_name="([^"]*)"', hlo):
+        if not op_name.startswith("jit(run_shard)/shard_map/"):
+            continue
+        scope = re.findall(r"/(gre\.[\w.]+)", op_name)
+        if scope:
+            scopes.add(scope[-1])
+        elif not SHARD_CONTROL.fullmatch(op_name):
+            outside.add(op_name)
+    assert not outside
+    assert scopes == {"gre.scatter", "gre.combine", "gre.apply",
+                      "gre.exchange.refresh", "gre.exchange.flush"}
+    collectives = [line for line in hlo.splitlines()
+                   if re.search(r"= \S+ all-to-all\(", line)]
+    assert len(collectives) >= 2
+    for line in collectives:
+        assert re.search(r'op_name="[^"]*/gre\.exchange\.(refresh|flush)/',
+                         line), line[:200]
+
+
 def _cell_shapes(name, sharding):
     """`(program, part, state)` with the shapes of the benchmark cell that
     runs `name` (abstract: nothing is allocated).  The statics come from a

@@ -171,3 +171,52 @@ def test_spans_outside_recording_are_not_kept():
         pass
     assert [n for n, _, _ in outer] == ["a", "c"]
     assert [n for n, _, _ in inner] == ["b"]
+
+
+@pytest.mark.parametrize("by_name", [False, True])
+def test_partitioning_and_topology_keep_their_spans(by_name):
+    """HDRF, the agent graph and the device topology are one span each, in
+    order; a partitioner named to `build_agent_graph` runs before its span
+    opens, so the two never nest."""
+    from repro.core.agent_graph import build_agent_graph
+    from repro.core.dist_engine import DistGREEngine
+    from repro.core.partition_stream import hdrf_partition
+    g = rmat_edges(scale=8, edge_factor=8, seed=3).dedup()
+    engine = DistGREEngine(algorithms.pagerank_program(),
+                           jax.make_mesh((1,), ("graph",)))
+    with spans.recording() as recorded:
+        t0 = time.perf_counter_ns()
+        placement = "hdrf" if by_name else hdrf_partition(g, 4)
+        ag = build_agent_graph(g, placement, 4)
+        engine.device_topology(ag)
+        t1 = time.perf_counter_ns()
+    assert [name for name, _, _ in recorded] == [
+        "gre.ingress.hdrf", "gre.ingress.agent_graph", "gre.ingress.topology"]
+    ends = [t0] + [x for _, s, e in recorded for x in (s, e)] + [t1]
+    assert ends == sorted(ends)
+
+
+def test_agent_graph_counters_match_a_recount():
+    """`AgentGraph.counters` against a numpy recount of the placement: an
+    agent is a (partition, vertex) pair where the partition holds an
+    out-edge (scatter) or an in-edge (combiner) of a vertex it does not
+    own; each exchange buffer is the widest peer pair, padded to 8."""
+    from repro.core.agent_graph import build_agent_graph
+    from repro.core.partition_stream import hdrf_partition
+    g = rmat_edges(scale=8, edge_factor=8, seed=3).dedup()
+    k = 4
+    part = hdrf_partition(g, k, batch_size=64)
+    ag = build_agent_graph(g, part, k)
+    owner = ag.old2new // ag.cap
+    scat = {(int(p), int(u)) for p, u in zip(part, g.src) if owner[u] != p}
+    comb = {(int(p), int(v)) for p, v in zip(part, g.dst) if owner[v] != p}
+    pairs = lambda agents: np.bincount(
+        [p * k + owner[v] for p, v in agents], minlength=k * k)
+    pad = lambda n: -(-max(1, int(n)) // 8) * 8
+    V = g.num_vertices
+    assert ag.counters() == {
+        "masters": V, "scatter_agents": len(scat),
+        "combiner_agents": len(comb),
+        "exchange_rows": k * k * (pad(pairs(scat).max())
+                                  + pad(pairs(comb).max())),
+        "replication_factor": (V + len(scat) + len(comb)) / V}
