@@ -318,8 +318,8 @@ def four_chips(gu, devices, refs):
                          eng.init_state(graph, source=source), steps))
     rows = {len(a.sharding.device_set)
             for job in jobs for a in jax.tree.leaves(job[3:5])}
-    check(rows == {4}, "every stacked array spread over the 4 devices, "
-          "one row each")
+    check(rows == {4}, "every topology and state array spread over the 4 "
+          "devices, one block each")
     log(f"set-up: device topologies + states {time.perf_counter() - t0:.1f}s")
 
     def compile_job(job):

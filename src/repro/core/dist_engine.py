@@ -102,7 +102,7 @@ class DistGREEngine:
     def __init__(self, program: VertexProgram, mesh: Mesh,
                  axis_names: Tuple[str, ...] = ("graph",),
                  exchange: str = "agent", overlap: bool = False,
-                 use_pallas: bool = False, frontier: str = "auto",
+                 use_pallas: Optional[bool] = None, frontier: str = "auto",
                  frontier_cap: Optional[int] = None,
                  dynamic_table: bool = True, plan=None, plan_cache=None,
                  staleness: int = 2):
@@ -125,6 +125,8 @@ class DistGREEngine:
         # frontier/frontier_cap select the per-shard scatter strategy
         # (engine.py); the lax.cond is shard-local and branch bodies have no
         # collectives, so shards may diverge dense-vs-compact per superstep.
+        # use_pallas unset: each shard's dense combine resolves its route
+        # per call over the shard's own columns (GREEngine.combine_route).
         self.local = GREEngine(program, use_pallas=use_pallas,
                                frontier=frontier, frontier_cap=frontier_cap,
                                dynamic_table=dynamic_table)
@@ -224,7 +226,10 @@ class DistGREEngine:
     # ----------------------------------------------------------- host → device
     @span("gre.ingress.topology")
     def device_topology(self, ag: AgentGraph):
-        """Stacked arrays [k, ...]; shard_map splits row i to device i.
+        """The partitions laid shard after shard: each host-stacked
+        `[k, n, ...]` array becomes `[k * n, ...]`, and shard_map hands
+        block i, partition i's own `[n, ...]` array, to device i
+        (`_put_shards`).
 
         The host span `gre.ingress.topology` (`repro.spans`) covers the
         host work and the enqueued copies, not their completion: a caller
@@ -239,7 +244,7 @@ class DistGREEngine:
         """
         if self._auto_plan_pending:
             self._resolve_auto_plan(ag)
-        put = self._put_rows
+        put = self._put_shards
         aux = {"out_degree": put(ag.out_degree),
                "global_id": put(
                    ag.new2old.reshape(ag.k, ag.cap).astype(np.float32))}
@@ -286,8 +291,18 @@ class DistGREEngine:
         is staged on the first device before shard_map splits it."""
         return jax.device_put(np.asarray(stacked), self._row_sharding)
 
+    def _put_shards(self, stacked):
+        """Place a host-stacked `[k, n, ...]` array as `[k * n, ...]`, block
+        i on mesh device i.  Inside shard_map a device's block is then the
+        shard's array itself: a stacked `[1, E]` edge column would need a
+        relayout to the `[E]` the gather and the Pallas combine read."""
+        a = np.asarray(stacked)
+        return jax.device_put(a.reshape((-1,) + a.shape[2:]),
+                              self._row_sharding)
+
     def _pipeline_tiles(self, ag: AgentGraph) -> PipelineTiles:
-        """Stacked remote/local edge tiles + compact-space exchange indices.
+        """Remote/local edge tiles + compact-space exchange indices, laid
+        shard after shard as `device_topology`'s arrays are.
 
         Exchange-index remapping rides the slot layout: combiner slots start
         at `cap + s_pad` and the padding fill is the sink
@@ -298,7 +313,7 @@ class DistGREEngine:
         """
         split = split_edge_tiles(ag)
         comb_base = ag.cap + ag.s_pad
-        put = self._put_rows
+        put = self._put_shards
 
         def tile_part(t, num_segments):
             # the tile's ⊕ runs over its compact segment space (see
@@ -519,8 +534,7 @@ class DistGREEngine:
             self._resolve_auto_plan(ag)
         spec_leading = self._row_sharding.spec
 
-        def tick_shard(topo_stack, state_stack):
-            topo_l = _squeeze0(topo_stack)
+        def tick_shard(topo_l, state_stack):
             s = _squeeze0(state_stack)
             backend = self.make_exchange(topo_l)
             for _ in range(steps_per_tick):
@@ -551,8 +565,9 @@ class DistGREEngine:
             # the phase stay matched across shards.
             return jax.lax.pmax(local.astype(jnp.int32), self.axes) > 0
 
-        def run_shard(topo_stack, state_stack):
-            topo_l = squeeze0(topo_stack)
+        def run_shard(topo_l, state_stack):
+            # the topology arrives laid shard after shard (device_topology):
+            # only the stacked state drops its leading axis
             state_l = squeeze0(state_stack)
             backend = self.make_exchange(topo_l)
             # the ONE driver loop (plan.execute_plan): the phase shape

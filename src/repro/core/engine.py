@@ -335,9 +335,12 @@ def resolve_combine_route(program: VertexProgram, part: DevicePartition,
     `table_length(E, num_segments)`), a scalar float32 payload and a sum,
     min or max monoid.  "pallas" runs the kernel where the program is
     lowered for a TPU; every other platform lowers the XLA scatter-reduce
-    (`segment_combine(use_pallas=None)`).  Everything else — the exchange
-    backends' agent and split-tile segment spaces, D-wide payloads,
-    integer payloads — resolves to "xla".
+    (`segment_combine(use_pallas=None)`).  Everything else — unsorted or
+    re-pointed `dst` columns (`AgentExchange(overlap=True)`), a table
+    built for another segment space, D-wide payloads, integer payloads —
+    resolves to "xla".  Each shard of `DistGREEngine` resolves the same
+    way over its own columns: the agent shard over its slot space, the
+    pipelined and async tiles over their compact spaces.
     """
     from repro.kernels.segment_combine import table_length
     table = part.combine_table

@@ -22,6 +22,7 @@ from repro.core.agent_graph import build_agent_graph
 from repro.core.dist_engine import DistGREEngine
 from repro.core.engine import DevicePartition, GREEngine
 from repro.core.partition import hash_partition
+from repro.core.plan import KernelPlan
 from repro.graph.generators import rmat_edges
 from repro.kernels import segment_combine as sc
 
@@ -297,3 +298,126 @@ def test_default_dense_combine_is_the_kernel_without_edge_copies(one_chip,
     temp = {k: c.memory_analysis().temp_size_in_bytes
             for k, c in compiled.items()}
     assert temp[None] <= 1.01 * temp[False]
+
+
+# one shard of the four-chip cell `rmat22-hdrf4.pagerank` as a run lays it
+# out: edge slots, masters (2**22 vertices over four chips), the slot space
+# and the exchange slots per peer
+AGENT_SHARD = {"edges": 16_311_768, "masters": 1 << 20, "slots": 1_745_849,
+               "exchange": 120_176}
+
+
+def _agent_cell_shapes(exchange, rows):
+    """`(program, agent graph, topology, state)` of the 4-device agent
+    PageRank with the shapes of `AGENT_SHARD` on each shard (abstract:
+    nothing is allocated).  The statics come from a small graph's agent
+    graph; the dense plan reads none of them.  Under the pipelined exchange
+    half the edges and half the agent slots are taken to be remote."""
+    from repro.core.partition_stream import hdrf_partition
+    k = 4
+    g = rmat_edges(scale=9, edge_factor=8, seed=2).dedup()
+    ag = build_agent_graph(g, hdrf_partition(g, k), k)
+    program = algorithms.pagerank_program()
+    host = DistGREEngine(program, jax.make_mesh((1,), ("graph",)),
+                         exchange=exchange)
+    small, state = host.device_topology(ag), host.init_state(ag)
+    e, cap, slots, x = AGENT_SHARD.values()
+    combiners = (slots - 1 - cap) // 2
+
+    def blocks(n, dtype, *rest):   # k shards' [n, *rest], shard after shard
+        return jax.ShapeDtypeStruct((k * n,) + rest, dtype, sharding=rows)
+
+    def with_edges(p, n, num_segments):
+        return dataclasses.replace(
+            p, src=blocks(n, jnp.int32), dst=blocks(n, jnp.int32),
+            edge_mask=blocks(n, jnp.bool_), num_masters=cap,
+            num_slots=slots,
+            edge_props={name: blocks(n, a.dtype)
+                        for name, a in p.edge_props.items()},
+            aux={name: blocks(cap, a.dtype) for name, a in p.aux.items()},
+            csr_indptr=blocks(slots + 1, jnp.int32),
+            csr_eidx=blocks(n, jnp.int32),
+            bucket_id=blocks(slots, jnp.int32),
+            combine_table=blocks(2, jnp.int32,
+                                 sc.table_length(n, num_segments)))
+
+    peers = lambda: blocks(k, jnp.int32, x)
+    if small.tiles is None:
+        part, tiles = with_edges(small.part, e, slots), None
+    else:
+        part = dataclasses.replace(
+            small.part, num_masters=cap, num_slots=slots,
+            aux={name: blocks(cap, a.dtype)
+                 for name, a in small.part.aux.items()})
+        tiles = dataclasses.replace(
+            small.tiles,
+            part_remote=with_edges(small.tiles.part_remote, e // 2,
+                                   combiners + 1),
+            part_local=with_edges(small.tiles.part_local, e - e // 2,
+                                  cap + 1),
+            comb_send_compact=peers(), comb_recv_master=peers(),
+            num_combiners=combiners)
+    topo_abs = dataclasses.replace(
+        small, part=part, tiles=tiles, comb_send_slot=peers(),
+        comb_recv_master=peers(), scat_send_master=peers(),
+        scat_recv_slot=peers())
+    stacked = lambda n, dtype: jax.ShapeDtypeStruct((k, n), dtype,
+                                                    sharding=rows)
+    state = dataclasses.replace(
+        state, vertex_data=stacked(cap, jnp.float32),
+        scatter_data=stacked(slots, jnp.float32),
+        active_scatter=stacked(slots, jnp.bool_),
+        step=jax.ShapeDtypeStruct((k,), jnp.int32, sharding=rows))
+    # each combine's (edges, segment space)
+    combines = ([(e, slots)] if tiles is None else
+                [(e // 2, combiners + 1), (e - e // 2, cap + 1)])
+    return program, ag, topo_abs, state, combines
+
+
+@pytest.mark.parametrize("exchange", ["agent", "pipelined"])
+def test_agent_shards_combine_on_the_kernel_without_edge_copies(topo,
+                                                                exchange):
+    """The 4-device agent PageRank run with the four-chip cell's shard
+    shapes, compiled for a `v5e:2x2`: by default each shard's dense combine
+    is the Pallas kernel under `gre.combine`, over the shard's slot space
+    (agent exchange) or over each split tile's own space (pipelined), with
+    no edge-sized pad, copy, transpose or relayout in the loop or hoisted
+    out of it.  `use_pallas=False`, and a tuned plan whose `KernelPlan`
+    holds False, compile no kernel."""
+    mesh = jax.sharding.Mesh(np.asarray(topo.devices[:4]), ("graph",))
+    rows = NamedSharding(mesh, P("graph"))
+    program, ag, topo_abs, state, combines = _agent_cell_shapes(exchange,
+                                                                rows)
+    default = DistGREEngine(program, mesh, ("graph",), exchange=exchange)
+    tuned = dataclasses.replace(default.plan,
+                                kernel=KernelPlan(use_pallas=False))
+    engines = {
+        "default": default,
+        "xla": DistGREEngine(program, mesh, ("graph",), exchange=exchange,
+                             use_pallas=False),
+        "tuned-xla": DistGREEngine(program, mesh, ("graph",),
+                                   exchange=exchange, plan=tuned)}
+    compiled = {name: eng.make_run(ag, max_steps=30).lower(
+        topo_abs, state).compile() for name, eng in engines.items()}
+    hlo = compiled["default"].as_text()
+    kernels = [line for line in hlo.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line]
+    assert kernels and all("gre.combine" in line for line in kernels)
+    # each kernel reads the block schedule of its own combine
+    schedules = {int(n) for line in kernels
+                 for n in re.findall(r"s32\[3,(\d+)\]", line)}
+    assert schedules == {sc.table_length(e, nseg) for e, nseg in combines}
+    for e, _ in combines:
+        assert edge_sized_copies(hlo, e) == []
+    for name in ("xla", "tuned-xla"):
+        assert "tpu_custom_call" not in compiled[name].as_text()
+    temp = {name: c.memory_analysis().temp_size_in_bytes
+            for name, c in compiled.items()}
+    if exchange == "agent":
+        assert temp["default"] <= 1.01 * temp["xla"]
+    else:
+        # the kernel's outputs are padded to whole dst blocks over two
+        # small segment spaces (+0.7 MB on 11 MB of temporaries); a copy
+        # of an edge column would add a whole tile's column
+        assert temp["default"] - temp["xla"] < 4 * min(e for e, _ in
+                                                      combines)
