@@ -2,39 +2,20 @@
 
 Each device owns one agent-graph partition (masters + agents + edge shard)
 and runs `GREEngine.superstep` — the SAME code path as the single-shard
-engine — with a pluggable ExchangeBackend supplying the communication:
+engine — with an ExchangeBackend (`repro.core.exchange`, where each is
+described) supplying the communication: "agent" (the paper's Agent-Graph,
+§5; `overlap=True` issues the remote flush first), "dense" (the
+hash-partition baseline), "pipelined" (the Agent-Graph over static
+remote/local edge tiles, the flush overlapping the local combine, §6.2),
+"async" (bounded staleness, monotone programs only) and "null" (a 1-device
+mesh, to A/B the shard_map plumbing).
 
-  exchange="agent"  → AgentExchange: scatter refresh (ONE message per
-      (master, peer) pair) before the local fused scatter-combine, combiner
-      flush (ONE ⊕-reduced message per agent) after it.  Total traffic per
-      superstep = |V_s| + |V_c| messages — the paper's §5.1 bound, strictly
-      ≤ vertex-cut's 2R.  `overlap=True` issues the remote-destined flush
-      before local-destined edges compute (§6.2's "override network
-      communication with useful computation", as an XLA scheduling hint).
-  exchange="dense"  → DenseExchange: hash-partition/Pregel baseline, a
-      collective ⊕ over the full relabeled vertex vector; used as the
-      communication baseline in benchmarks and rooflines.
-  exchange="pipelined" → PipelinedAgentExchange: the Agent-Graph protocol
-      over a static ingress-time remote/local edge split
-      (`agent_graph.split_edge_tiles`) — the flush collective for superstep
-      i is issued before the local-tile combine and merged at the top of
-      superstep i+1 (double-buffered `Mailbox`), overlapping communication
-      with computation (paper §6.2) at E edge-scans per superstep where
-      `overlap=True` needs 2·E.
-  exchange="async" → AsyncAgentExchange: bounded-staleness execution over
-      the same split tiles — the Mailbox generalized to a `staleness=k`
-      deep ring so remote partials cross shards only once per k supersteps
-      (one refresh + one flush collective per WINDOW instead of per step)
-      while local updates merge eagerly every step.  Monotone ⊕=min/max
-      halting programs only (`VertexProgram.monotone`); sum-monoid
-      programs refuse with ValueError at construction.
-
-Every backend runs through the SAME driver loop: the engine's
-`SuperstepPlan` (repro.core.plan) selects the exchange phase shape
-("sync" vs "pipelined" vs "async") from the backend and
-`plan.execute_plan` drives it per shard.  This module owns only backend/plan selection, host→device
-topology layout, and state relabeling; all superstep logic lives in
-engine.py/exchange.py/plan.py.
+Every backend runs through the SAME driver loop (`plan.execute_plan`), and
+the state lifecycle — seeding, the serving admit step, the warm start and
+tuned-plan adoption — is the single-shard engine's applied per shard
+(`engine.EngineLifecycle`).  This module owns only what exists across
+chips: backend selection, the host→device topology layout, and the map
+between original vertex ids and (shard, slot).
 """
 from __future__ import annotations
 
@@ -47,7 +28,8 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.agent_graph import AgentGraph, split_edge_tiles
-from repro.core.engine import DevicePartition, EngineState, GREEngine
+from repro.core.engine import (DevicePartition, EngineLifecycle,
+                               EngineState, GREEngine, seed_operands)
 from repro.core.exchange import (AgentExchange, AsyncAgentExchange,
                                  DenseExchange, NullExchange,
                                  PipelinedAgentExchange, PipelineTiles,
@@ -94,7 +76,7 @@ def _check_async_eligible(program: VertexProgram) -> None:
             f"Use exchange='agent' or 'pipelined' instead.")
 
 
-class DistGREEngine:
+class DistGREEngine(EngineLifecycle):
     """Runs a VertexProgram over an AgentGraph on a device mesh."""
 
     EXCHANGES = ("agent", "dense", "null", "pipelined", "async")
@@ -130,21 +112,15 @@ class DistGREEngine:
         self.local = GREEngine(program, use_pallas=use_pallas,
                                frontier=frontier, frontier_cap=frontier_cap,
                                dynamic_table=dynamic_table)
-        # plan=SuperstepPlan adopts the composed mode now (its phase shape
-        # picks between the Agent-Graph protocol's sync and pipelined
-        # variants); plan="auto-tuned" defers to the persistent tuned-plan
-        # cache, consulted — keyed by (agent-graph fingerprint, program
-        # payload, MESH SIZE) — the first time an AgentGraph is in hand
-        # (device_topology/init_state/make_run), before any topology or
-        # trace bakes in the static shapes.  Misses keep the knobs above.
-        self._plan_cache = plan_cache
-        self._auto_plan_pending = False
-        if plan is None:
-            pass
-        elif plan == "auto-tuned":
-            self._auto_plan_pending = True
-        else:
-            self.adopt_plan(plan)
+        # plan="auto-tuned" consults the cache keyed by (agent-graph
+        # fingerprint, program payload, MESH SIZE) the first time an
+        # AgentGraph is in hand (device_topology/init_state/make_run)
+        self._init_plan(plan, plan_cache)
+
+    @property
+    def shard_engine(self) -> GREEngine:
+        """The engine whose frontier and kernel knobs every shard runs."""
+        return self.local
 
     def adopt_plan(self, plan) -> None:
         """Take a composed SuperstepPlan mesh-wide: the frontier/kernel
@@ -166,25 +142,41 @@ class DistGREEngine:
         elif self.exchange in ("pipelined", "async"):
             self.exchange = "agent"
 
-    def _resolve_auto_plan(self, ag: AgentGraph) -> None:
-        """`plan="auto-tuned"` resolution against the persistent cache
-        (see `GREEngine._consult_plan_cache`); the key folds in the mesh
-        size, the agent graph's remote-destination edge fraction, and the
-        partitioner that produced the placement (`AgentGraph.partitioner`,
-        recorded when `build_agent_graph` is handed a partitioner name) —
-        the fingerprint facets a single-shard tuning run can't see, and
-        the facet that keeps a plan tuned on a greedy placement from
-        answering for an HDRF one."""
-        self._auto_plan_pending = False
-        from repro.tuning import PlanCache, plan_cache_key
-        cache = self._plan_cache
-        if not isinstance(cache, PlanCache):
-            cache = PlanCache(cache)
-        key = plan_cache_key(agent_graph=ag, program=self.program,
-                             mesh_size=self.mesh.size)
-        plan = cache.lookup(key)
-        if plan is not None:
-            self.adopt_plan(plan)
+    def _cache_key(self, ag: AgentGraph) -> str:
+        """The tuned-plan cache key folds in the mesh size, the agent
+        graph's remote-destination edge fraction, and the partitioner that
+        produced the placement (`AgentGraph.partitioner`) — the facets a
+        single-shard tuning run can't see, and the one that keeps a plan
+        tuned on a greedy placement from answering for an HDRF one."""
+        from repro.tuning import plan_cache_key
+        return plan_cache_key(agent_graph=ag, program=self.program,
+                              mesh_size=self.mesh.size)
+
+    def _apply_edge_delta(self, ag: AgentGraph, delta):
+        from repro.core.agent_graph import apply_edge_delta
+        return apply_edge_delta(ag, delta)
+
+    def _original_layout(self, ag: AgentGraph):
+        """Master row `old2new[v]` of the stacked `[k, cap]` rows holds
+        vertex v; `slot_to_original` names every edge end."""
+        from repro.core.agent_graph import slot_to_original
+        s2o = slot_to_original(ag)
+        m = ag.edge_mask
+        prop = self.program.needs_edge_prop
+        edges = (np.take_along_axis(s2o, ag.src, axis=1)[m],
+                 np.take_along_axis(s2o, ag.dst, axis=1)[m],
+                 np.asarray(ag.edge_props[prop])[m] if prop else None)
+        aux = {"out_degree": jnp.asarray(np.asarray(ag.out_degree).reshape(
+                   ag.k * ag.cap)[ag.old2new]),
+               "global_id": jnp.arange(ag.num_vertices, dtype=jnp.float32)}
+        return ag.old2new, edges, aux
+
+    @staticmethod
+    def _aux(ag: AgentGraph) -> dict:
+        """The apply-phase aux of every shard, host-stacked `[k, cap]`."""
+        return {"out_degree": ag.out_degree,
+                "global_id": ag.new2old.reshape(ag.k, ag.cap).astype(
+                    np.float32)}
 
     @property
     def plan(self):
@@ -243,11 +235,9 @@ class DistGREEngine:
         memory for arrays the split-tile paths never read.
         """
         if self._auto_plan_pending:
-            self._resolve_auto_plan(ag)
+            self._consult_plan(ag)
         put = self._put_shards
-        aux = {"out_degree": put(ag.out_degree),
-               "global_id": put(
-                   ag.new2old.reshape(ag.k, ag.cap).astype(np.float32))}
+        aux = {name: put(a) for name, a in self._aux(ag).items()}
         if self.exchange in ("pipelined", "async"):
             part = DevicePartition(
                 num_masters=ag.cap, num_slots=ag.num_slots,
@@ -285,11 +275,6 @@ class DistGREEngine:
         """Row i of a stacked `[k, ...]` array lives on mesh device i."""
         return NamedSharding(self.mesh, P(self.axes if len(self.axes) > 1
                                           else self.axes[0]))
-
-    def _put_rows(self, stacked):
-        """Place a host-stacked `[k, ...]` array row-per-device, so no shard
-        is staged on the first device before shard_map splits it."""
-        return jax.device_put(np.asarray(stacked), self._row_sharding)
 
     def _put_shards(self, stacked):
         """Place a host-stacked `[k, n, ...]` array as `[k * n, ...]`, block
@@ -346,145 +331,46 @@ class DistGREEngine:
                    lane_tracking: bool = False):
         """Stacked initial state [k, ...]; `source` is an ORIGINAL vertex id,
         or — for `payload_shape=(D,)` multi-source programs — a length-D
-        sequence of original ids (source d seeds payload lane d; a `None`
-        or negative entry leaves lane d empty for later admission).
+        sequence of original ids (see `GREEngine.init_state`).
 
-        `lane_tracking=True` attaches the per-lane halt vector (replicated
-        `[k, D]` bool, kept mesh-global by the serving tick's pmax) so the
-        serving layer can retire converged lanes between supersteps."""
+        Each shard's state is `GREEngine.shard_state` over its own aux and
+        seed slots (`_seed_slots`: the owner's local slot, the sentinel on
+        every other shard), run for all shards under one `jax.vmap` and
+        placed row-per-device in one `device_put`.  `lane_tracking=True`
+        attaches the per-lane halt vector (replicated `[k, D]` bool, kept
+        mesh-global by the serving tick's pmax)."""
         if self._auto_plan_pending:
-            self._resolve_auto_plan(ag)
-        p = self.program
-        k, cap, slots = ag.k, ag.cap, ag.num_slots
-        aux = {"out_degree": jnp.asarray(ag.out_degree),   # [k, cap]
-               "global_id": jnp.asarray(
-                   ag.new2old.reshape(k, cap).astype(np.float32))}
-        vd = jax.vmap(lambda a: p.init_vertex_data(cap, a))(aux)
-        sd0 = jax.vmap(lambda a: jnp.asarray(p.init_scatter_data(cap, a),
-                                             p.msg_dtype))(aux)
-        sd = jnp.full((k, slots) + sd0.shape[2:], p.monoid.identity,
-                      p.msg_dtype).at[:, :cap].set(sd0)
-        act = jnp.zeros((k, slots), dtype=bool)
-        act = act.at[:, :cap].set(
-            jax.vmap(lambda a: p.init_active(cap, a))(aux))
+            self._consult_plan(ag)
+        ids, lanes, row = seed_operands(self.program, source, lane_tracking)
+        aux = {name: jnp.asarray(a) for name, a in self._aux(ag).items()}
+        slots = (None if ids is None
+                 else jnp.asarray(self._seed_slots(ag, ids)))
+        state = jax.vmap(lambda a, s: self.local.shard_state(
+            ag.cap, ag.num_slots, a, s, lanes, row))(aux, slots)
         # mask padding masters (no original vertex)
-        real = jnp.asarray(ag.new2old.reshape(k, cap) >= 0)
-        act = act.at[:, :cap].set(act[:, :cap] & real)
-        seeded = []
-        if source is not None:
-            multi = isinstance(source, (list, tuple, np.ndarray))
-            act = jnp.zeros_like(act)
-            for d, sv in enumerate(source if multi else [source]):
-                ok = sv is not None and int(sv) >= 0
-                seeded.append(ok)
-                if not ok:
-                    continue
-                g = int(ag.old2new[int(sv)])
-                i, s = g // cap, g % cap
-                if multi:  # seed payload lane d only
-                    if p.seed_sources is not None:
-                        aux_i = {kk: v[i] for kk, v in aux.items()}
-                        vd_i, sd_i = p.seed_sources(
-                            vd[i], sd[i], jnp.array([s], jnp.int32),
-                            jnp.array([d], jnp.int32), aux_i)
-                        vd = vd.at[i].set(vd_i)
-                        sd = sd.at[i].set(sd_i)
-                    else:
-                        vd = vd.at[i, s, d].set(0.0)
-                        sd = sd.at[i, s, d].set(0.0)
-                else:
-                    vd = vd.at[i, s].set(0.0)
-                    sd = sd.at[i, s].set(0.0)
-                act = act.at[i, s].set(True)
-        lane_active = None
-        if lane_tracking:
-            if p.lane_activates is None or not p.payload_shape:
-                raise ValueError(
-                    "lane_tracking needs a multi-source program with "
-                    "lane_activates (per-lane halt rule)")
-            D = p.payload_shape[0]
-            if len(seeded) not in (0, D):
-                raise ValueError(f"expected {D} source entries")
-            row = np.zeros(D, dtype=bool) if not seeded else np.array(seeded)
-            lane_active = jnp.broadcast_to(jnp.asarray(row)[None, :], (k, D))
+        real = jnp.asarray(ag.new2old.reshape(ag.k, ag.cap) >= 0)
+        act = state.active_scatter.at[:, :ag.cap].set(
+            state.active_scatter[:, :ag.cap] & real)
         return jax.device_put(
-            EngineState(vd, sd, act, jnp.zeros((k,), jnp.int32),
-                        lane_active), self._row_sharding)
+            dataclasses.replace(state, active_scatter=act),
+            self._row_sharding)
 
-    # ------------------------------------------------------------ incremental
-    def warm_start_state(self, ag: AgentGraph, prev_state: EngineState,
-                         report, source=None, lane_tracking: bool = False):
-        """Distributed warm start (see `GREEngine.warm_start_state`): the
-        invalidation/seeding passes run host-side in ORIGINAL vertex order
-        — `old2new` maps master rows out of the stacked `[k, cap, ...]`
-        state and back — so the policy logic (repro.core.incremental) is
-        shared verbatim with the single-shard engine.  `ag` is the
-        MUTATED agent graph (`agent_graph.apply_edge_delta` preserves
-        master placement, so `prev_state`'s rows line up)."""
-        from repro.core import incremental
-        from repro.core.agent_graph import slot_to_original
-        p = self.program
-        incremental.check_supported(p, report)
-        k, cap, V = ag.k, ag.cap, ag.num_vertices
-        state0 = self.init_state(ag, source=source,
-                                 lane_tracking=lane_tracking)
-        if not p.halts:
-            return dataclasses.replace(
-                state0,
-                vertex_data=prev_state.vertex_data,
-                scatter_data=state0.scatter_data.at[:, :cap].set(
-                    prev_state.scatter_data[:, :cap]))
+    @staticmethod
+    def _seed_slots(ag: AgentGraph, ids) -> np.ndarray:
+        """Original ids -> per-shard seed slots `[k, len(ids)]`: vertex v's
+        local master slot on the shard that owns it, the sentinel
+        `num_slots` on every other shard and for -1."""
+        out = np.full((ag.k, ids.size), ag.num_slots, np.int32)
+        seeded = np.flatnonzero(ids >= 0)
+        g = ag.old2new[ids[seeded]]
+        out[g // ag.cap, seeded] = g % ag.cap
+        return out
 
-        def to_orig(stacked):   # [k, cap, ...] master rows -> [V, ...]
-            a = np.asarray(stacked)
-            return a.reshape((k * cap,) + a.shape[2:])[ag.old2new]
-
-        vd_prev = to_orig(prev_state.vertex_data)
-        sd_prev = to_orig(np.asarray(prev_state.scatter_data)[:, :cap])
-        s2o = slot_to_original(ag)
-        lsrc, ldst, lprop = [], [], []
-        for i in range(k):
-            m = ag.edge_mask[i]
-            lsrc.append(s2o[i][ag.src[i]][m])
-            ldst.append(s2o[i][ag.dst[i]][m])
-            if p.needs_edge_prop:
-                lprop.append(ag.edge_props[p.needs_edge_prop][i][m])
-        lsrc = np.concatenate(lsrc)
-        ldst = np.concatenate(ldst)
-        eprop = np.concatenate(lprop) if p.needs_edge_prop else None
-        protected = incremental.source_mask(vd_prev.shape, source)
-        tainted = incremental.compute_taint(p, V, lsrc, ldst, eprop,
-                                            vd_prev, report, protected)
-        vd = np.where(tainted, to_orig(state0.vertex_data), vd_prev)
-        sd = np.where(tainted,
-                      to_orig(np.asarray(state0.scatter_data)[:, :cap]),
-                      sd_prev)
-        tany = tainted if tainted.ndim == 1 else tainted.any(axis=-1)
-        aux_orig = {
-            "out_degree": jnp.asarray(
-                np.asarray(ag.out_degree).reshape(k * cap)[ag.old2new]),
-            "global_id": jnp.arange(V, dtype=jnp.float32)}
-        init_act = np.asarray(p.init_active(V, aux_orig))
-        act = incremental.warm_seed_active(V, lsrc, ldst, tany,
-                                           report.added_src, init_act)
-        # scatter the original-order columns back into the stacked layout
-        vd_st = np.asarray(state0.vertex_data).reshape(
-            (k * cap,) + vd.shape[1:]).copy()
-        vd_st[ag.old2new] = vd
-        vd_st = vd_st.reshape((k, cap) + vd.shape[1:])
-        sd_full = np.asarray(state0.scatter_data).copy()
-        sd_flat = sd_full[:, :cap].reshape((k * cap,) + sd.shape[1:]).copy()
-        sd_flat[ag.old2new] = sd
-        sd_full[:, :cap] = sd_flat.reshape((k, cap) + sd.shape[1:])
-        act_flat = np.zeros(k * cap, dtype=bool)
-        act_flat[ag.old2new] = act
-        act_st = np.zeros((k, ag.num_slots), dtype=bool)
-        act_st[:, :cap] = act_flat.reshape(k, cap)
-        return dataclasses.replace(
-            state0,
-            vertex_data=jnp.asarray(vd_st, vd_prev.dtype),
-            scatter_data=jnp.asarray(sd_full, p.msg_dtype),
-            active_scatter=jnp.asarray(act_st))
+    def to_original(self, ag: AgentGraph, vertex_data) -> np.ndarray:
+        """Stacked `[k, cap, ...]` master rows -> `[V, ...]` on the host in
+        ORIGINAL vertex order."""
+        vd = np.asarray(jax.device_get(vertex_data))
+        return vd.reshape((ag.k * ag.cap,) + vd.shape[2:])[ag.old2new]
 
     def rerun_incremental(self, ag: AgentGraph, prev_state: EngineState,
                           delta, *, source=None, max_steps: int = 100):
@@ -493,18 +379,25 @@ class DistGREEngine:
         ``(new_ag, result_in_original_order, final_state, report)`` —
         bitwise-equal to a cold `run` on the mutated graph for halting
         min-monoid programs (tests/test_conformance.py)."""
-        from repro.core.agent_graph import apply_edge_delta
-        new_ag, report = apply_edge_delta(ag, delta)
+        new_ag, report = self.apply_delta(ag, delta)
         state = self.warm_start_state(new_ag, prev_state, report,
                                       source=source)
         topo = self.device_topology(new_ag)
-        fn = self.make_run(new_ag, max_steps=max_steps)
-        out = jax.device_get(fn(topo, state))
-        vd = np.asarray(out.vertex_data).reshape(
-            (new_ag.k * new_ag.cap,) + out.vertex_data.shape[2:])
-        result = np.empty((new_ag.num_vertices,) + vd.shape[1:], vd.dtype)
-        result[:] = vd[new_ag.old2new]
-        return new_ag, result, out, report
+        out = jax.device_get(self.make_run(new_ag, max_steps)(topo, state))
+        return new_ag, self.to_original(new_ag, out.vertex_data), out, report
+
+    def _admit_step(self, ag: AgentGraph):
+        """`GREEngine.admit` per shard under `jax.vmap`, inside the
+        engine's shard_map so every stacked operand keeps the state's
+        row-per-device placement.  `lane_active` stays replicated: every
+        shard applies the same lane update."""
+        rows = self._row_sharding.spec
+        step = jax.vmap(self.local.admit, in_axes=(0, 0, 0, None, None))
+        fn = jax.jit(shard_map(step, mesh=self.mesh,
+                               in_specs=(rows, rows, rows, P(), P()),
+                               out_specs=rows))
+        # placed row-per-device, so no shard is staged on the first device
+        return fn, jax.device_put(self._aux(ag), self._row_sharding)
 
     # ------------------------------------------------------------------ tick
     def make_superstep(self, ag: AgentGraph, steps_per_tick: int = 1):
@@ -531,7 +424,7 @@ class DistGREEngine:
                 "supersteps, and a per-tick merge would drop them. Use "
                 "exchange='agent' or 'pipelined' for serving.")
         if self._auto_plan_pending:
-            self._resolve_auto_plan(ag)
+            self._consult_plan(ag)
         spec_leading = self._row_sharding.spec
 
         def tick_shard(topo_l, state_stack):
@@ -554,7 +447,7 @@ class DistGREEngine:
     def make_run(self, ag: AgentGraph, max_steps: int = 100):
         """Build the jitted distributed run function over the mesh."""
         if self._auto_plan_pending:
-            self._resolve_auto_plan(ag)
+            self._consult_plan(ag)
         spec_leading = self._row_sharding.spec
         squeeze0, unsqueeze0 = _squeeze0, _unsqueeze0
 
@@ -588,9 +481,5 @@ class DistGREEngine:
         topo = self.device_topology(ag)
         state = self.init_state(ag, source=source)
         fn = self.make_run(ag, max_steps=max_steps)
-        out = fn(topo, state)
-        out = jax.device_get(out)
-        vd = np.asarray(out.vertex_data).reshape(ag.k * ag.cap, *out.vertex_data.shape[2:])
-        result = np.empty((ag.num_vertices,) + vd.shape[1:], vd.dtype)
-        result[:] = vd[ag.old2new]
-        return result, out
+        out = jax.device_get(fn(topo, state))
+        return self.to_original(ag, out.vertex_data), out

@@ -387,7 +387,172 @@ class EngineState:
     counters: Optional[jnp.ndarray] = None     # [rows, 3] int32, opt-in
 
 
-class GREEngine:
+def seed_operands(program: VertexProgram, source, lane_tracking: bool = False):
+    """`init_state`'s `source` on the host, as ``(ids, lanes, lane_row)``:
+    the original ids to seed (-1 leaves a lane unseeded), the lane each
+    seeds (None for a scalar source, which seeds its whole row) and the
+    `lane_active` row (seeded lanes start active), each None when absent."""
+    ids = lanes = row = None
+    if source is not None and np.ndim(source) == 0:
+        ids = np.array([int(source)])
+    elif source is not None:
+        ids = np.array([-1 if sv is None or int(sv) < 0 else int(sv)
+                        for sv in source])
+        lanes = jnp.arange(ids.size)
+    if lane_tracking:
+        width = program.payload_shape[0] if program.payload_shape else None
+        if (program.lane_activates is None or width is None
+                or ids is not None and (lanes is None or ids.size != width)):
+            raise ValueError("lane_tracking needs a program with "
+                             "`lane_activates` (payload_shape=(D,)) and no "
+                             "source or a sequence of D sources")
+        row = jnp.asarray(np.zeros(width, bool) if ids is None else ids >= 0)
+    return ids, lanes, row
+
+
+class EngineLifecycle:
+    """The state lifecycle around the superstep, written once for both
+    engines: plan adoption and the tuned-plan cache, applying a delta, the
+    warm start and the serving admit step.  A `target` is a
+    `DevicePartition` (`GREEngine`) or an `AgentGraph` (`DistGREEngine`);
+    each engine supplies only its layout (`_cache_key`, `_apply_edge_delta`,
+    `_original_layout`, `_seed_slots`, `_admit_step`).  The per-shard state
+    pieces (`fresh_state`, `seed`, `admit`) are `GREEngine`'s, which the
+    mesh applies per shard."""
+
+    def _init_plan(self, plan, plan_cache) -> None:
+        """`plan=SuperstepPlan(...)` overrides the knob-by-knob arguments
+        now; `plan="auto-tuned"` consults the tuned-plan cache (PlanCache at
+        `plan_cache`, else the default location) the first time a target is
+        in hand, before any trace fixes the static shapes: a hit adopts the
+        stored plan without any probe execution, a miss keeps the knobs."""
+        self._plan_cache = plan_cache
+        self._auto_plan_pending = plan == "auto-tuned"
+        self._plan_key = None     # the consulted key, for `refresh_plan`
+        if plan is not None and not self._auto_plan_pending:
+            self.adopt_plan(plan)
+
+    def _consult_plan(self, target) -> None:
+        self._auto_plan_pending = False
+        self._rekey(self._cache_key(target))
+
+    def refresh_plan(self, target) -> bool:
+        """Re-key a consulted tuned plan after a graph mutation.
+
+        The fingerprint quantizes its facets (log2 edge counts, skew
+        bins), so a small delta is ABSORBED — same key, the adopted plan
+        stands and no retrace happens.  A large delta shifts a bin: the
+        stale key (plans tuned for the pre-mutation graph silently
+        governing the mutated one) is dropped, the cache is consulted under
+        the new key (hit = adopt, miss = keep current knobs), and the new
+        key becomes current.  Returns True when the key changed.  No-op
+        unless this engine ever consulted the cache (`plan="auto-tuned"`).
+        """
+        if self._plan_key is None:
+            return False
+        return self._rekey(self._cache_key(target))
+
+    def _rekey(self, key: str) -> bool:
+        if key == self._plan_key:
+            return False
+        self._plan_key = key
+        from repro.tuning import PlanCache
+        cache = self._plan_cache
+        if not isinstance(cache, PlanCache):
+            cache = PlanCache(cache)
+        plan = cache.lookup(key)
+        if plan is not None:
+            self.adopt_plan(plan)
+        return True
+
+    def apply_delta(self, target, delta):
+        """Apply an EdgeDelta to `target` (docs/incremental.md) and re-key
+        a consulted tuned plan against the result (`refresh_plan`) before
+        anything traces on it.  Returns ``(new_target, DeltaReport)``."""
+        new, report = self._apply_edge_delta(target, delta)
+        self.refresh_plan(new)
+        return new, report
+
+    # ------------------------------------------------------------ incremental
+    def warm_start_state(self, target, prev_state: EngineState, report,
+                         source=None, lane_tracking: bool = False
+                         ) -> EngineState:
+        """Seed a re-convergence run on the MUTATED target from the
+        previous fixed point (repro.core.incremental; docs/incremental.md).
+
+        Iterative programs (PageRank) carry the previous values forward
+        under fresh init activity.  Halting min-monoid traversals reset the
+        entries the surviving edges no longer certify (the program's
+        `invalidation` policy), and only add-endpoints, in-neighbors of
+        resets and self-seeding resets start active; an empty delta yields
+        an empty frontier.  The policy runs in ORIGINAL vertex order
+        (`incremental.warm_start_columns`); the layout maps master rows out
+        and back — the identity on one shard, `old2new` on a mesh (whose
+        `agent_graph.apply_edge_delta` preserves master placement)."""
+        from repro.core import incremental
+        p = self.program
+        incremental.check_supported(p, report)
+        state0 = self.init_state(target, source=source,
+                                 lane_tracking=lane_tracking)
+        # the master rows: [:n] of a shard's arrays, [:, :cap] of a stack
+        lead = state0.step.ndim
+        rows = (slice(None),) * lead + (
+            slice(None, state0.vertex_data.shape[lead]),)
+        if not p.halts:
+            return dataclasses.replace(
+                state0, vertex_data=prev_state.vertex_data,
+                scatter_data=state0.scatter_data.at[rows].set(
+                    prev_state.scatter_data[rows]))
+        order, edges, aux = self._original_layout(target)
+
+        def columns(a):     # master rows -> [V, ...] in original order
+            a = np.asarray(a)[rows]
+            return a.reshape((-1,) + a.shape[lead + 1:])[order]
+
+        def place(cols, base):   # original-order columns into master rows
+            out = np.array(base)
+            block = np.array(out[rows])
+            block.reshape((-1,) + block.shape[lead + 1:])[order] = cols
+            out[rows] = block
+            return out
+
+        vd, sd, act = incremental.warm_start_columns(
+            p, report, source, edges, aux,
+            prev=(columns(prev_state.vertex_data),
+                  columns(prev_state.scatter_data)),
+            init=(columns(state0.vertex_data),
+                  columns(state0.scatter_data)))
+        return dataclasses.replace(
+            state0,
+            vertex_data=jnp.asarray(place(vd, state0.vertex_data),
+                                    prev_state.vertex_data.dtype),
+            scatter_data=jnp.asarray(place(sd, state0.scatter_data),
+                                     p.msg_dtype),
+            active_scatter=jnp.asarray(place(
+                act, np.zeros(state0.active_scatter.shape, bool))))
+
+    # ---------------------------------------------------------------- serving
+    def make_admit(self, target):
+        """The serving admit step: ONE jitted static-shape call,
+        ``admit(state, sources, lanes)`` over `[D]`-wide host arrays.
+        `lanes[i]` names a lane to reset (sentinel D = no-op), `sources[i]`
+        the ORIGINAL vertex id to seed into it (-1 = reset only: eviction),
+        and the lane's `lane_active` bit becomes whether it was seeded.
+        Sources map to seed slots whose out-of-bounds-HIGH sentinel
+        `mode="drop"` discards, so admission never recompiles."""
+        fn, aux = self._admit_step(target)
+
+        def admit(state, sources, lanes):
+            sources = np.asarray(sources)
+            return fn(aux, state,
+                      jnp.asarray(self._seed_slots(target, sources)),
+                      jnp.asarray(lanes, jnp.int32),
+                      jnp.asarray(sources >= 0))
+
+        return admit
+
+
+class GREEngine(EngineLifecycle):
     """Drives a VertexProgram over one DevicePartition.
 
     `frontier` selects the scatter strategy (core/frontier.py):
@@ -445,40 +610,25 @@ class GREEngine:
         # the monoid identity so padded edges still contribute nothing).
         self.dense_frontier = (dense_frontier if dense_frontier is not None
                                else not program.halts)
-        # `plan` overrides the knob-by-knob arguments with one composed
-        # SuperstepPlan, or requests a persisted tuned plan:
-        #   plan=SuperstepPlan(...)  — adopt its stages now;
-        #   plan="auto-tuned"       — consult the tuned-plan cache
-        #       (repro.tuning.cache.PlanCache at `plan_cache`, else the
-        #       default location) the first time a partition is in hand
-        #       (init_state — the last eager point before the jitted run
-        #       traces its static tile shapes).  Cache hits adopt the
-        #       stored plan without any probe execution; misses keep the
-        #       defaults above.
         # `bucket_bounds` records the degree-bucket ladder an adopted tuned
         # plan was probed against (None = partition default); callers
         # rebuild matching partitions via
         # DevicePartition.from_graph(bucket_bounds=...).
         self.bucket_bounds = None
         self.frontier_hist = None   # set by calibrate_frontier_cap
-        self._plan_cache = plan_cache
-        self._auto_plan_pending = False
-        # last consulted tuned-plan cache key + its frontier-hist facet —
-        # `refresh_plan` re-keys against these after a graph mutation
-        self._plan_key = None
-        self._plan_hist = None
-        if plan is None:
-            pass
-        elif plan == "auto-tuned":
-            self._auto_plan_pending = True
-        else:
-            self.adopt_plan(plan)
+        self._plan_hist = None      # the cache key's frontier facet
+        self._init_plan(plan, plan_cache)
+
+    @property
+    def shard_engine(self) -> "GREEngine":
+        return self   # the engine whose knobs every shard runs
 
     def adopt_plan(self, plan: SuperstepPlan) -> None:
         """Take a composed SuperstepPlan's stages as this engine's knobs
-        (the inverse of `make_plan`).  Must run before the first jitted
-        `run` trace — the adopted frontier capacity and kernel route are
-        static compile-time decisions (same contract as
+        (the inverse of `make_plan`), the kernel route included: an unset
+        route (None) stays unset.  Must run before the first jitted `run`
+        trace — the adopted frontier capacity and kernel route are static
+        compile-time decisions (same contract as
         `calibrate_frontier_cap`)."""
         assert plan.strategy in self.FRONTIERS, plan.strategy
         self.frontier = plan.strategy
@@ -488,55 +638,23 @@ class GREEngine:
         self.dynamic_table = plan.kernel.dynamic_table
         self.bucket_bounds = plan.bucket_bounds
 
-    def _consult_plan_cache(self, part: DevicePartition,
-                            state: EngineState) -> None:
-        """`plan="auto-tuned"` resolution: probe the live frontier
-        histogram (the fingerprint's density facet — the same measurement
-        `tune()` keys its stored plans by), look the partition's
-        fingerprint up in the persistent plan cache (repro.tuning); a hit
-        adopts the stored plan (no evaluator probes run — the whole point
-        of the cache), a miss keeps the engine's defaults."""
-        self._auto_plan_pending = False
-        from repro.tuning import PlanCache, plan_cache_key
-        cache = self._plan_cache
-        if not isinstance(cache, PlanCache):
-            cache = PlanCache(cache)
-        hist = self.probe_frontier_hist(part, state)
-        key = plan_cache_key(part=part, program=self.program, mesh_size=1,
-                             frontier_hist=hist)
-        self._plan_key, self._plan_hist = key, hist
-        plan = cache.lookup(key)
-        if plan is not None:
-            self.adopt_plan(plan)
+    def _cache_key(self, part: DevicePartition) -> str:
+        # with the frontier histogram probed at consult time (`init_state`)
+        from repro.tuning import plan_cache_key
+        return plan_cache_key(part=part, program=self.program, mesh_size=1,
+                              frontier_hist=self._plan_hist)
 
-    def refresh_plan(self, part: DevicePartition) -> bool:
-        """Re-key a consulted tuned plan after a graph mutation.
+    def _apply_edge_delta(self, part: DevicePartition, delta):
+        return part.apply_edge_delta(delta, bucket_bounds=self.bucket_bounds)
 
-        The fingerprint quantizes its facets (log2 edge counts, skew
-        bins), so a small `apply_edge_delta` is ABSORBED — same key, the
-        adopted plan stands and no retrace happens.  A large delta shifts
-        a bin: the stale key (the bug this fixes — plans tuned for the
-        pre-mutation graph silently governing the mutated one) is dropped,
-        the cache is consulted under the new key (hit = adopt, miss = keep
-        current knobs), and the new key becomes current.  Returns True
-        when the key changed.  No-op unless this engine ever consulted
-        the cache (`plan="auto-tuned"`).
-        """
-        if self._plan_key is None:
-            return False
-        from repro.tuning import PlanCache, plan_cache_key
-        key = plan_cache_key(part=part, program=self.program, mesh_size=1,
-                             frontier_hist=self._plan_hist)
-        if key == self._plan_key:
-            return False
-        self._plan_key = key
-        cache = self._plan_cache
-        if not isinstance(cache, PlanCache):
-            cache = PlanCache(cache)
-        plan = cache.lookup(key)
-        if plan is not None:
-            self.adopt_plan(plan)
-        return True
+    def _original_layout(self, part: DevicePartition):
+        # one shard's layout is the original order: master slot v is vertex v
+        mask = np.asarray(part.edge_mask)
+        prop = self.program.needs_edge_prop
+        edges = (np.asarray(part.src)[mask].astype(np.int64),
+                 np.asarray(part.dst)[mask].astype(np.int64),
+                 np.asarray(part.edge_props[prop])[mask] if prop else None)
+        return np.arange(part.num_masters), edges, part.aux
 
     def make_plan(self, phases: str = "sync",
                   staleness: int = 0) -> SuperstepPlan:
@@ -551,7 +669,7 @@ class GREEngine:
             strategy=self.frontier, frontier_cap=self.frontier_cap,
             dense_frontier=self.dense_frontier, phases=phases,
             staleness=staleness,
-            kernel=KernelPlan(use_pallas=bool(self.use_pallas),
+            kernel=KernelPlan(use_pallas=self.use_pallas,
                               dynamic_table=self.dynamic_table))
 
     def _frontier_plan(self, part: DevicePartition):
@@ -606,6 +724,58 @@ class GREEngine:
         return hist
 
     # ------------------------------------------------------------------ init
+    def fresh_state(self, num_masters: int, num_slots: int, aux,
+                    counters: int = 0) -> EngineState:
+        """One shard's state before seeding: the program's initial values
+        and activity on the masters, the monoid identity elsewhere."""
+        p = self.program
+        n = num_masters
+        vertex_data = p.init_vertex_data(n, aux)
+        sd0 = jnp.asarray(p.init_scatter_data(n, aux), p.msg_dtype)
+        scatter_data = jnp.full((num_slots,) + sd0.shape[1:],
+                                p.monoid.identity, p.msg_dtype).at[:n].set(sd0)
+        active = jnp.zeros(num_slots, dtype=bool).at[:n].set(
+            p.init_active(n, aux))
+        return EngineState(
+            vertex_data, scatter_data, active, jnp.zeros((), jnp.int32),
+            counters=jnp.zeros((counters, 3), jnp.int32) if counters
+            else None)
+
+    def seed(self, state: EngineState, slots, lanes, aux) -> EngineState:
+        """The seeding step: seed each `(slots[i], lanes[i])` pair (whole
+        rows for `lanes=None`) and activate its slot; a slot at or past
+        `num_slots` is the sentinel and drops.  It goes through the
+        program's `seed_sources` hook when it has one (PPR stages its
+        first push), else sets 0.0 at `[slot, lane]`."""
+        p = self.program
+        vd, sd = state.vertex_data, state.scatter_data
+        if lanes is None:
+            vd = vd.at[slots].set(0.0, mode="drop")
+            sd = sd.at[slots].set(0.0, mode="drop")
+        elif p.seed_sources is not None:
+            vd, sd = p.seed_sources(vd, sd, slots, lanes, aux)
+        else:
+            vd = vd.at[slots, lanes].set(0.0, mode="drop")
+            sd = sd.at[slots, lanes].set(0.0, mode="drop")
+        return dataclasses.replace(
+            state, vertex_data=vd, scatter_data=sd,
+            active_scatter=state.active_scatter.at[slots].set(
+                True, mode="drop"))
+
+    def shard_state(self, num_masters: int, num_slots: int, aux,
+                    slots=None, lanes=None, lane_row=None,
+                    counters: int = 0) -> EngineState:
+        """`fresh_state`, then — given seed slots — `seed` from an empty
+        frontier (`DistGREEngine.init_state` vmaps this over shards)."""
+        state = dataclasses.replace(
+            self.fresh_state(num_masters, num_slots, aux, counters),
+            lane_active=lane_row)
+        if slots is None:
+            return state
+        return self.seed(dataclasses.replace(
+            state, active_scatter=jnp.zeros(num_slots, dtype=bool)),
+            slots, lanes, aux)
+
     def init_state(self, part: DevicePartition, source=None,
                    lane_tracking: bool = False,
                    counters: int = 0) -> EngineState:
@@ -616,125 +786,36 @@ class GREEngine:
         Multi-source seeding is LANE-MASKED: entries that are None or
         negative leave their lane unseeded (identity values, inactive) —
         the serving layer starts with fewer queries than lanes and admits
-        into the free lanes later.  Seeding goes through the program's
-        `seed_sources` hook when it has one (PPR stages its first push);
-        the default is the traversal convention (0.0 at `[src, lane]`).
+        into the free lanes later (`seed_operands`, `seed`).
 
         `lane_tracking=True` attaches the per-lane halt tracker
         (`EngineState.lane_active`, seeded lanes start active); requires a
-        multi-source program exposing `lane_activates`.
+        program exposing `lane_activates` and no source or D sources.
 
         `counters=rows` attaches the per-superstep work record
         (`EngineState.counters`, `[rows, 3]` int32 zeros); `run` refuses
         fewer rows than its `max_steps`.  It reads the partition's CSR
         layout, so a partition without one is refused.
         """
-        p = self.program
-        n, s = part.num_masters, part.num_slots
-        vertex_data = p.init_vertex_data(n, part.aux)
-        sd0 = jnp.asarray(p.init_scatter_data(n, part.aux), p.msg_dtype)
-        scatter_data = jnp.full((s,) + sd0.shape[1:], p.monoid.identity,
-                                p.msg_dtype).at[:n].set(sd0)
-        active = jnp.zeros(s, dtype=bool).at[:n].set(p.init_active(n, part.aux))
-        lane_active = None
-        multi = source is not None and np.ndim(source) > 0
-        if source is not None and not multi:
-            src_idx = jnp.asarray(source, jnp.int32)
-            vertex_data = vertex_data.at[src_idx].set(0.0)
-            scatter_data = scatter_data.at[src_idx].set(0.0)
-            active = jnp.zeros(s, dtype=bool).at[src_idx].set(True)
-        elif multi:  # one source per payload lane, None/-1 = lane unseeded
-            seeded = np.array([sv is not None and int(sv) >= 0
-                               for sv in source])
-            src_np = np.array([int(sv) if ok else s
-                               for sv, ok in zip(source, seeded)], np.int32)
-            src_idx = jnp.asarray(src_np)          # sentinel s drops
-            lanes = jnp.arange(src_idx.shape[0])
-            if p.seed_sources is not None:
-                vertex_data, scatter_data = p.seed_sources(
-                    vertex_data, scatter_data, src_idx, lanes, part.aux)
-            else:
-                vertex_data = vertex_data.at[src_idx, lanes].set(
-                    0.0, mode="drop")
-                scatter_data = scatter_data.at[src_idx, lanes].set(
-                    0.0, mode="drop")
-            active = jnp.zeros(s, dtype=bool).at[src_idx].set(
-                True, mode="drop")
-            if lane_tracking:
-                lane_active = jnp.asarray(seeded)
-        if lane_tracking and (lane_active is None
-                              or p.lane_activates is None):
-            raise ValueError("lane_tracking needs a multi-source (sequence) "
-                             "`source` and a program with `lane_activates` "
-                             "(payload_shape=(D,))")
+        ids, lanes, row = seed_operands(self.program, source, lane_tracking)
         if counters and part.csr_indptr is None:
             raise ValueError("counters need the partition's CSR layout "
                              "(csr_indptr), which this partition lacks")
-        state = EngineState(
-            vertex_data, scatter_data, active, jnp.zeros((), jnp.int32),
-            lane_active,
-            jnp.zeros((counters, 3), jnp.int32) if counters else None)
+        slots = (None if ids is None
+                 else jnp.asarray(self._seed_slots(part, ids)))
+        state = self.shard_state(part.num_masters, part.num_slots, part.aux,
+                                 slots, lanes, row, counters)
         if self._auto_plan_pending:
             # plan="auto-tuned": the seeded state is the last eager point
             # before a jitted run trace fixes the static tile shapes, and
             # the cache key's frontier-density facet needs it
-            self._consult_plan_cache(part, state)
+            self._plan_hist = self.probe_frontier_hist(part, state)
+            self._consult_plan(part)
         return state
 
-    # ------------------------------------------------------------ incremental
-    def warm_start_state(self, part: DevicePartition, prev_state: EngineState,
-                         report, source=None, lane_tracking: bool = False
-                         ) -> EngineState:
-        """Seed a re-convergence run on the MUTATED partition from the
-        previous fixed point (repro.core.incremental; docs/incremental.md).
-
-        Iterative programs (PageRank) carry the previous values forward
-        under fresh init activity — the contraction resumes from a nearby
-        point.  Halting min-monoid traversals get the exact treatment:
-        entries no longer certified by the surviving edges are reset to
-        their initial values (the program's `invalidation` policy), and
-        only add-endpoints, in-neighbors of resets, and self-seeding
-        resets start active.  An empty delta yields an empty frontier —
-        the run terminates immediately at the previous fixed point.
-        """
-        from repro.core import incremental
-        p = self.program
-        incremental.check_supported(p, report)
-        n = part.num_masters
-        state0 = self.init_state(part, source=source,
-                                 lane_tracking=lane_tracking)
-        if not p.halts:
-            return dataclasses.replace(
-                state0,
-                vertex_data=prev_state.vertex_data,
-                scatter_data=state0.scatter_data.at[:n].set(
-                    prev_state.scatter_data[:n]))
-        vd_prev = np.asarray(prev_state.vertex_data)
-        sd_prev = np.asarray(prev_state.scatter_data)[:n]
-        src = np.asarray(part.src)
-        mask = np.asarray(part.edge_mask)
-        lsrc = src[mask].astype(np.int64)
-        ldst = np.asarray(part.dst)[mask].astype(np.int64)
-        eprop = None
-        if p.needs_edge_prop:
-            eprop = np.asarray(part.edge_props[p.needs_edge_prop])[mask]
-        protected = incremental.source_mask(vd_prev.shape, source)
-        tainted = incremental.compute_taint(p, n, lsrc, ldst, eprop,
-                                            vd_prev, report, protected)
-        vd = np.where(tainted, np.asarray(state0.vertex_data), vd_prev)
-        sd = np.where(tainted, np.asarray(state0.scatter_data)[:n], sd_prev)
-        tany = tainted if tainted.ndim == 1 else tainted.any(axis=-1)
-        init_act = np.asarray(p.init_active(n, part.aux))
-        act = incremental.warm_seed_active(n, lsrc, ldst, tany,
-                                           report.added_src, init_act)
-        active = jnp.zeros(part.num_slots, dtype=bool).at[:n].set(
-            jnp.asarray(act))
-        return dataclasses.replace(
-            state0,
-            vertex_data=jnp.asarray(vd, np.asarray(vd_prev).dtype),
-            scatter_data=state0.scatter_data.at[:n].set(
-                jnp.asarray(sd, p.msg_dtype)),
-            active_scatter=active)
+    def _seed_slots(self, part: DevicePartition, ids) -> np.ndarray:
+        # vertex v's slot is v; -1 becomes the sentinel `num_slots`
+        return np.where(ids >= 0, ids, part.num_slots).astype(np.int32)
 
     def rerun_incremental(self, part: DevicePartition, prev_state: EngineState,
                           delta, *, source=None, max_steps: int = 100,
@@ -749,14 +830,66 @@ class GREEngine:
         carry.  Supersteps and edge scans are proportional to the
         perturbation, not the graph (benchmarks/bench_incremental.py).
         """
-        new_part, report = part.apply_edge_delta(
-            delta, bucket_bounds=self.bucket_bounds)
+        new_part, report = self.apply_delta(part, delta)
         state = self.warm_start_state(new_part, prev_state, report,
                                       source=source,
                                       lane_tracking=lane_tracking)
-        self.refresh_plan(new_part)
-        out = self.run(new_part, state, max_steps)
-        return new_part, out, report
+        return new_part, self.run(new_part, state, max_steps), report
+
+    # ---------------------------------------------------------------- serving
+    def device_topology(self, part: DevicePartition) -> DevicePartition:
+        return part   # what the tick runs over
+
+    def to_original(self, part: DevicePartition, vertex_data) -> np.ndarray:
+        return np.asarray(jax.device_get(vertex_data))   # slot v = vertex v
+
+    def make_superstep(self, part: DevicePartition, steps_per_tick: int = 1):
+        """The jitted SERVING TICK, ``tick(part, state)``: `steps_per_tick`
+        supersteps, no convergence loop.  The partition is an ARGUMENT: jit
+        embeds closed-over arrays as constants, a copy of the topology."""
+        def tick(part, state):
+            for _ in range(steps_per_tick):
+                state = self.superstep(part, state)
+            return state
+
+        return jax.jit(tick)
+
+    def admit(self, aux, state: EngineState, slots, lanes,
+              flags) -> EngineState:
+        """One shard's serving admit step (see `make_admit`): reset the
+        named lanes to their fresh values, normalize stale seed rows, then
+        `seed`; `flags` become the lanes' `lane_active` bits."""
+        p = self.program
+        D = lanes.shape[0]
+        identity = p.monoid.identity
+        fresh = self.fresh_state(state.vertex_data.shape[0],
+                                 state.scatter_data.shape[0], aux)
+        mask = jnp.zeros(D, dtype=bool).at[lanes].set(True, mode="drop")
+        vd = state.vertex_data
+        vd = jnp.where(mask.reshape((1, D) + (1,) * (vd.ndim - 2)),
+                       fresh.vertex_data, vd)
+        sd = jnp.where(mask[None, :], fresh.scatter_data, state.scatter_data)
+        # Activating the seed vertex makes it scatter EVERY lane of its row
+        # next superstep.  An inactive vertex's row is stale — its values
+        # were already delivered (sum monoids would double-count them) — so
+        # normalize it to the identity; an ACTIVE vertex's row was
+        # rewritten by the last apply and is still undelivered, so it must
+        # be kept.
+        rows = jnp.take(sd, slots, axis=0, mode="fill", fill_value=identity)
+        keep = jnp.take(state.active_scatter, slots, mode="fill",
+                        fill_value=False)
+        rows = jnp.where(keep.reshape((D,) + (1,) * (rows.ndim - 1)),
+                         rows, identity)
+        sd = sd.at[slots].set(rows, mode="drop")
+        state = self.seed(dataclasses.replace(state, vertex_data=vd,
+                                              scatter_data=sd),
+                          slots, lanes, aux)
+        return dataclasses.replace(
+            state, lane_active=state.lane_active.at[lanes].set(
+                flags, mode="drop"))
+
+    def _admit_step(self, part: DevicePartition):
+        return jax.jit(self.admit), part.aux   # aux an argument, as above
 
     # ------------------------------------------------------- scatter-combine
     def scatter_combine(self, part: DevicePartition, state: EngineState,

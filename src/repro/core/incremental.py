@@ -175,3 +175,29 @@ def warm_seed_active(num_vertices, live_src, live_dst, tainted_any,
             act[live_src[into_taint]] = True
         act |= tainted_any & init_active
     return act
+
+
+def warm_start_columns(program, report, source, edges, aux, prev, init):
+    """The warm start's policy on ORIGINAL-order master columns, for both
+    engines (`EngineLifecycle.warm_start_state` maps each layout's master
+    rows out and back): reset the entries the delta no longer certifies to
+    their fresh values, and pick the vertices that start active.
+
+    `edges` is the live `(src, dst, prop)` in original ids, `aux` the
+    program's init aux in original order, `prev` and `init` the
+    `(vertex_data, scatter_data)` columns of the previous fixed point and
+    of the freshly seeded state.  Returns the `(vertex_data, scatter_data,
+    active)` columns.
+    """
+    live_src, live_dst, live_prop = edges
+    vd_prev, sd_prev = prev
+    n = vd_prev.shape[0]
+    tainted = compute_taint(program, n, live_src, live_dst, live_prop,
+                            vd_prev, report,
+                            source_mask(vd_prev.shape, source))
+    tainted_any = tainted if tainted.ndim == 1 else tainted.any(axis=-1)
+    active = warm_seed_active(n, live_src, live_dst, tainted_any,
+                              report.added_src,
+                              np.asarray(program.init_active(n, aux)))
+    return (np.where(tainted, init[0], vd_prev),
+            np.where(tainted, init[1], sd_prev), active)

@@ -63,15 +63,19 @@ class FrontierPlan(NamedTuple):
 class KernelPlan:
     """The combine-kernel stage of a plan.
 
-    `use_pallas=False` is the XLA scatter-reduce (`segment_combine`).  With
-    `use_pallas=True` gathered frontier tiles route through the Pallas tile
-    combine; `dynamic_table` selects the on-device per-superstep
-    `dynamic_block_table` pruning pass (default) vs the degenerate
-    `full_block_table` fallback (every dst block visits every edge block —
-    kept only as the documented escape hatch, see docs/kernels.md).
+    `use_pallas` is the engine's kernel route (`GREEngine.use_pallas`):
+    False forces the XLA scatter-reduce (`segment_combine`), True the
+    Pallas kernels, and None leaves it unset — the dense scan then resolves
+    its route per call (`resolve_combine_route`) and gathered frontier
+    tiles keep XLA.  With `use_pallas=True` the tiles route through the
+    Pallas tile combine; `dynamic_table` selects the on-device
+    per-superstep `dynamic_block_table` pruning pass (default) vs the
+    degenerate `full_block_table` fallback (every dst block visits every
+    edge block — kept only as the documented escape hatch, see
+    docs/kernels.md).
     """
 
-    use_pallas: bool = False
+    use_pallas: Optional[bool] = False
     dynamic_table: bool = True
 
 
@@ -190,7 +194,8 @@ class SuperstepPlan:
         if kunknown:
             raise ValueError(f"SuperstepPlan.from_json: unknown kernel "
                              f"field(s) {sorted(kunknown)}")
-        kernel = KernelPlan(use_pallas=bool(kdata.get("use_pallas", False)),
+        route = kdata.get("use_pallas", False)
+        kernel = KernelPlan(use_pallas=None if route is None else bool(route),
                             dynamic_table=bool(kdata.get("dynamic_table",
                                                          True)))
         cap = data.get("frontier_cap")

@@ -111,7 +111,7 @@ def run_graph_engine_dryrun(mesh) -> dict:
             backend = eng.make_exchange(topo_l)
 
             def body(i, s):
-                return eng.local.superstep(topo_l.part, s, backend)
+                return eng.shard_engine.superstep(topo_l.part, s, backend)
 
             out = _jax.lax.fori_loop(0, 30, body, st)
             return _jax.tree.map(lambda a: a[None], out)

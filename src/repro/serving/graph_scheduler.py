@@ -37,7 +37,6 @@ from collections import deque
 from typing import Dict, List, Optional, Sequence
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 __all__ = ["GraphQueryBatcher", "Query", "ServingFrontend", "poisson_ticks"]
@@ -103,7 +102,11 @@ class GraphQueryBatcher:
     `engine` is a `GREEngine` (with a `DevicePartition` target) or a
     `DistGREEngine` (with an `AgentGraph` target); the program must be a
     multi-source variant exposing `lane_activates` (e.g.
-    `bfs_program(D)`, `sssp_program(D)`, `ppr_push_program(D)`).
+    `bfs_program(D)`, `sssp_program(D)`, `ppr_push_program(D)`).  The
+    batcher never branches on which: each engine builds the tick
+    (`make_superstep`, run over `device_topology(target)`), the admit step
+    (`make_admit`), reads `vertex_data` back in original order
+    (`to_original`) and swaps the target after a delta (`apply_delta`).
 
     Public protocol: `submit()` enqueues; `pump()` retires/evicts/admits
     (host-side, between ticks); `tick()` advances every resident lane by
@@ -124,21 +127,8 @@ class GraphQueryBatcher:
         self.steps_per_tick = steps_per_tick
         self.default_budget = default_budget
         self.clock = clock
-        self._dist = hasattr(engine, "mesh")   # DistGREEngine
-        if self._dist:
-            self._ag = target
-            self._topo = engine.device_topology(target)
-            self._tick_fn = engine.make_superstep(
-                target, steps_per_tick=steps_per_tick)
-            self._admit_fn = self._make_dist_admit(target)
-            self.state = engine.init_state(
-                target, source=[None] * self.num_lanes, lane_tracking=True)
-        else:
-            self._part = target
-            self._tick_fn = self._make_tick(target)
-            self._admit_fn = self._make_admit(target)
-            self.state = engine.init_state(
-                target, source=[None] * self.num_lanes, lane_tracking=True)
+        self._target = target
+        self._build()
         # After init_state/device_topology: any plan="auto-tuned" cache hit
         # has been adopted by now, and the jitted tick/admit fns above trace
         # lazily on first call — so the clamp below still lands before any
@@ -175,140 +165,21 @@ class GraphQueryBatcher:
         reset to the program's own default instead."""
         if self.program.monoid.name != "sum":
             return
-        local = self.engine.local if self._dist else self.engine
+        local = self.engine.shard_engine
         local.frontier = "dense"
         local.frontier_cap = None
         local.dense_frontier = not self.program.halts
 
-    # ------------------------------------------------------------ jitted fns
-    def _make_tick(self, part):
-        engine, steps = self.engine, self.steps_per_tick
-
-        def tick(part, state):
-            for _ in range(steps):
-                state = engine.superstep(part, state)
-            return state
-
-        # the partition is an ARGUMENT, not a closure: jit embeds closed-over
-        # arrays in the program as constants, a copy of the whole topology
-        tick = jax.jit(tick)
-        return lambda state: tick(part, state)
-
-    def _make_admit(self, part):
-        """ONE static-shape admission/eviction/reset call.
-
-        Operands are `[D]`-wide: `lanes[i]` names the lane to reset (sentinel
-        D = no-op), `src[i]` the root to seed into it (sentinel `num_slots`
-        = reset WITHOUT seeding, i.e. eviction), `flags[i]` the lane's new
-        `lane_active` bit.  Sentinels are out-of-bounds-HIGH so
-        `mode="drop"` discards them (negative indices would wrap).
-        """
-        p, D = self.program, self.num_lanes
-        n, slots = part.num_masters, part.num_slots
-        identity = p.monoid.identity
-
-        def admit(aux, state, src, lanes, flags):
-            mask = jnp.zeros(D, dtype=bool).at[lanes].set(True, mode="drop")
-            init_vd = p.init_vertex_data(n, aux)
-            vd = state.vertex_data
-            bmask = mask.reshape((1, D) + (1,) * (vd.ndim - 2))
-            vd = jnp.where(bmask, init_vd, vd)
-            sd0 = jnp.asarray(p.init_scatter_data(n, aux), p.msg_dtype)
-            sd_init = jnp.full((slots,) + sd0.shape[1:], identity,
-                               p.msg_dtype).at[:n].set(sd0)
-            sd = jnp.where(mask[None, :], sd_init, state.scatter_data)
-            # Activating the seed vertex makes it scatter EVERY lane of its
-            # row next superstep.  An inactive vertex's row is stale — its
-            # values were already delivered (sum monoids would double-count
-            # them) — so normalize it to the identity; an ACTIVE vertex's
-            # row was rewritten by the last apply and is still undelivered,
-            # so it must be kept.
-            rows = jnp.take(sd, src, axis=0, mode="fill",
-                            fill_value=identity)
-            keep = jnp.take(state.active_scatter, src, mode="fill",
-                            fill_value=False)
-            rows = jnp.where(keep.reshape((D,) + (1,) * (rows.ndim - 1)),
-                             rows, identity)
-            sd = sd.at[src].set(rows, mode="drop")
-            if p.seed_sources is not None:
-                vd, sd = p.seed_sources(vd, sd, src, lanes, aux)
-            else:
-                vd = vd.at[src, lanes].set(0.0, mode="drop")
-                sd = sd.at[src, lanes].set(0.0, mode="drop")
-            active = state.active_scatter.at[src].set(True, mode="drop")
-            lane_active = state.lane_active.at[lanes].set(flags, mode="drop")
-            return dataclasses.replace(
-                state, vertex_data=vd, scatter_data=sd,
-                active_scatter=active, lane_active=lane_active)
-
-        admit = jax.jit(admit)   # aux as an argument, as in `_make_tick`
-        return lambda state, src, lanes, flags: admit(part.aux, state, src,
-                                                      lanes, flags)
-
-    def _make_dist_admit(self, ag):
-        """Distributed admission: same contract, stacked `[k, ...]` state.
-
-        `src` here is `[k, D]` — a seeded lane's root appears as a LOCAL
-        slot on exactly the shard that masters it (sentinel `num_slots`
-        everywhere else), so the vmapped per-shard body is identical to the
-        single-shard one.  `lane_active` stays replicated: row 0 is updated
-        and broadcast.  Like the tick, the call runs under shard_map over
-        the engine's mesh, so every stacked operand keeps the state's
-        row-per-device placement.
-        """
-        from jax.sharding import PartitionSpec as P
-
-        from repro.dist.sharding import shard_map
-        engine = self.engine
-        p, D = self.program, self.num_lanes
-        cap, slots = ag.cap, ag.num_slots
-        identity = p.monoid.identity
-        aux = {"out_degree": engine._put_rows(ag.out_degree),
-               "global_id": engine._put_rows(
-                   ag.new2old.reshape(ag.k, cap).astype(np.float32))}
-
-        def one_shard(vd, sd, act, aux_i, src_i, lanes, mask):
-            init_vd = p.init_vertex_data(cap, aux_i)
-            bmask = mask.reshape((1, D) + (1,) * (vd.ndim - 2))
-            vd = jnp.where(bmask, init_vd, vd)
-            sd0 = jnp.asarray(p.init_scatter_data(cap, aux_i), p.msg_dtype)
-            sd_init = jnp.full((slots,) + sd0.shape[1:], identity,
-                               p.msg_dtype).at[:cap].set(sd0)
-            sd = jnp.where(mask[None, :], sd_init, sd)
-            # same stale-row normalization as the single-shard admit (an
-            # inactive seed vertex's row was already delivered)
-            rows = jnp.take(sd, src_i, axis=0, mode="fill",
-                            fill_value=identity)
-            keep = jnp.take(act, src_i, mode="fill", fill_value=False)
-            rows = jnp.where(keep.reshape((D,) + (1,) * (rows.ndim - 1)),
-                             rows, identity)
-            sd = sd.at[src_i].set(rows, mode="drop")
-            if p.seed_sources is not None:
-                vd, sd = p.seed_sources(vd, sd, src_i, lanes, aux_i)
-            else:
-                vd = vd.at[src_i, lanes].set(0.0, mode="drop")
-                sd = sd.at[src_i, lanes].set(0.0, mode="drop")
-            act = act.at[src_i].set(True, mode="drop")
-            return vd, sd, act
-
-        def admit_shard(state, aux, src, lanes, flags):
-            mask = jnp.zeros(D, dtype=bool).at[lanes].set(True, mode="drop")
-            vd, sd, act = jax.vmap(
-                lambda v, s, a, x, si: one_shard(v, s, a, x, si, lanes, mask)
-            )(state.vertex_data, state.scatter_data, state.active_scatter,
-              aux, src)
-            row = state.lane_active[0].at[lanes].set(flags, mode="drop")
-            la = jnp.broadcast_to(row[None, :], state.lane_active.shape)
-            return dataclasses.replace(
-                state, vertex_data=vd, scatter_data=sd, active_scatter=act,
-                lane_active=la)
-
-        rows = engine._row_sharding.spec
-        fn = jax.jit(shard_map(admit_shard, mesh=engine.mesh,
-                               in_specs=(rows, rows, rows, P(), P()),
-                               out_specs=rows))
-        return lambda state, src, lanes, flags: fn(state, aux, src, lanes,
-                                                   flags)
+    def _build(self) -> None:
+        """Build the jitted tick and admit fns and a fresh lane state for
+        the current target."""
+        engine, target = self.engine, self._target
+        self._topo = engine.device_topology(target)
+        self._tick_fn = engine.make_superstep(
+            target, steps_per_tick=self.steps_per_tick)
+        self._admit_fn = engine.make_admit(target)
+        self.state = engine.init_state(
+            target, source=[None] * self.num_lanes, lane_tracking=True)
 
     # --------------------------------------------------------------- serving
     def submit(self, source: int, *, kind: Optional[str] = None,
@@ -334,15 +205,7 @@ class GraphQueryBatcher:
 
     def _lane_active_host(self) -> np.ndarray:
         la = np.asarray(jax.device_get(self.state.lane_active))
-        return la[0] if la.ndim == 2 else la
-
-    def _vertex_data_host(self) -> np.ndarray:
-        vd = np.asarray(jax.device_get(self.state.vertex_data))
-        if not self._dist:
-            return vd
-        ag = self._ag
-        flat = vd.reshape(ag.k * ag.cap, *vd.shape[2:])
-        return flat[ag.old2new]   # back to ORIGINAL vertex order
+        return la.reshape(-1, self.num_lanes)[0]   # a mesh replicates it
 
     def _lane_result(self, vd_host: np.ndarray, lane: int) -> np.ndarray:
         if self.program.lane_view is not None:
@@ -358,9 +221,7 @@ class GraphQueryBatcher:
         finished: List[Query] = []
         la = self._lane_active_host()
         vd_host = None
-        ops: Dict[int, int] = {}   # lane -> src (sentinel = reset only)
-        sentinel_src = (self._ag.num_slots if self._dist
-                        else self._part.num_slots)
+        ops: Dict[int, int] = {}   # lane -> source (-1 = reset only)
         now = self.clock()
         for d in range(D):
             q = self._lane_query[d]
@@ -368,7 +229,8 @@ class GraphQueryBatcher:
                 continue
             if not la[d]:            # converged: fetch result, free the lane
                 if vd_host is None:
-                    vd_host = self._vertex_data_host()
+                    vd_host = self.engine.to_original(
+                        self._target, self.state.vertex_data)
                 q.result = self._lane_result(vd_host, d)
                 q.status, q.finished_at = "done", now
                 finished.append(q)
@@ -378,7 +240,7 @@ class GraphQueryBatcher:
                 q.status, q.finished_at = "evicted", now   # budget exceeded
                 finished.append(q)
                 self._lane_query[d] = None
-                ops[d] = sentinel_src        # reset the lane, seed nothing
+                ops[d] = -1                  # reset the lane, seed nothing
         # "finish"-policy deltas land here: every resident lane has drained
         # (their results above were fetched from the pre-delta snapshot),
         # so the swap is between ticks by construction — never torn.  A
@@ -394,33 +256,21 @@ class GraphQueryBatcher:
                 q.status, q.lane, q.admitted_at = "running", d, now
                 q.supersteps_used = 0
                 self._lane_query[d] = q
-                ops[d] = self._local_src(q.source)   # admit overrides evict
+                ops[d] = q.source            # admit overrides evict
         if ops:
             self._apply_ops(ops)
         self.finished.extend(finished)
         return finished
 
     def _apply_ops(self, ops: Dict[int, int]) -> None:
-        """ONE jitted admit call applying `lane -> src` transitions
-        (sentinel src = reset without seeding)."""
+        """ONE jitted admit call applying `lane -> source` transitions
+        (source -1 = reset without seeding)."""
         D = self.num_lanes
-        sentinel_src = (self._ag.num_slots if self._dist
-                        else self._part.num_slots)
         lanes = np.full(D, D, np.int32)              # sentinel lane = D
-        flags = np.zeros(D, dtype=bool)
-        src = (np.full((self._ag.k, D), sentinel_src, np.int32)
-               if self._dist else np.full(D, sentinel_src, np.int32))
+        sources = np.full(D, -1, np.int64)
         for i, (d, s) in enumerate(ops.items()):
-            lanes[i] = d
-            if isinstance(s, tuple):                 # dist admit: seed on
-                shard, slot = s                      # the mastering shard
-                src[shard, i] = slot
-                flags[i] = True
-            elif s != sentinel_src:                  # single-shard admit
-                src[i] = s
-                flags[i] = True
-        self.state = self._admit_fn(self.state, jnp.asarray(src),
-                                    jnp.asarray(lanes), jnp.asarray(flags))
+            lanes[i], sources[i] = d, s
+        self.state = self._admit_fn(self.state, sources, lanes)
 
     # ------------------------------------------------------- graph mutation
     def apply_delta(self, delta, *, policy: str = "finish") -> None:
@@ -455,8 +305,7 @@ class GraphQueryBatcher:
                      if q is not None]
         self._swap_target()
         if residents:
-            self._apply_ops({d: self._local_src(q.source)
-                             for d, q in residents})
+            self._apply_ops({d: q.source for d, q in residents})
 
     def _swap_target(self) -> None:
         """Apply every pending delta to the topology and rebuild the jitted
@@ -464,49 +313,21 @@ class GraphQueryBatcher:
         lane holds a query whose state must survive (drained, or about to
         be re-seeded)."""
         deltas, self._pending_deltas = self._pending_deltas, []
-        if self._dist:
-            from repro.core.agent_graph import apply_edge_delta
-            for delta in deltas:
-                self._ag, _ = apply_edge_delta(self._ag, delta)
-            self._topo = self.engine.device_topology(self._ag)
-            self._tick_fn = self.engine.make_superstep(
-                self._ag, steps_per_tick=self.steps_per_tick)
-            self._admit_fn = self._make_dist_admit(self._ag)
-            self.state = self.engine.init_state(
-                self._ag, source=[None] * self.num_lanes,
-                lane_tracking=True)
-        else:
-            for delta in deltas:
-                self._part, _ = self._part.apply_edge_delta(delta)
-            # stale-PlanCache fix: a mutated partition re-keys the tuned
-            # plan before the new tick function traces
-            self.engine.refresh_plan(self._part)
-            self._tick_fn = self._make_tick(self._part)
-            self._admit_fn = self._make_admit(self._part)
-            self.state = self.engine.init_state(
-                self._part, source=[None] * self.num_lanes,
-                lane_tracking=True)
-        # refresh_plan / re-keyed cache hits can adopt a compacted plan for
-        # the mutated graph; sum-monoid lanes must stay dense (see
+        for delta in deltas:
+            # the engine re-keys a tuned plan against the mutated graph
+            # before the new tick function traces
+            self._target, _ = self.engine.apply_delta(self._target, delta)
+        self._build()
+        # re-keyed cache hits can adopt a compacted plan for the mutated
+        # graph; sum-monoid lanes must stay dense (see
         # `_clamp_sum_monoid_plan`).
         self._clamp_sum_monoid_plan()
-
-    def _local_src(self, source: int):
-        """Original vertex id → admit-operand encoding: the local slot
-        (single shard) or a (shard, local_slot) pair (distributed)."""
-        if not self._dist:
-            return int(source)
-        g = int(self._ag.old2new[int(source)])
-        return (g // self._ag.cap, g % self._ag.cap)
 
     def tick(self) -> None:
         """Advance every resident lane by `steps_per_tick` supersteps."""
         self._busy_lane_ticks += sum(
             q is not None for q in self._lane_query)
-        if self._dist:
-            self.state = self._tick_fn(self._topo, self.state)
-        else:
-            self.state = self._tick_fn(self.state)
+        self.state = self._tick_fn(self._topo, self.state)
         self.ticks += 1
         self.supersteps += self.steps_per_tick
         for q in self._lane_query:

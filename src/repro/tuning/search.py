@@ -117,7 +117,9 @@ def tune(program, graph, *, source=0, cache=None,
                               meta.get("default_us", 0.0), key,
                               from_cache=True, num_probes=0)
 
-    default_plan = GREEngine(program).make_plan()
+    # the default candidate in the search space's own terms: its kernel
+    # stages name a route (XLA's segment ops), never an unset one
+    default_plan = GREEngine(program, use_pallas=False).make_plan()
     dense = default_plan.dense_frontier
     cands = list(space.candidates(part.num_slots,
                                   default_cap(part.num_slots, hist),

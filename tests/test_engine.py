@@ -11,7 +11,7 @@ import jax.numpy as jnp
 from repro.core import algorithms
 from repro.core.engine import (DevicePartition, GREEngine,
                                resolve_combine_route)
-from repro.core.plan import XLA_KERNEL
+from repro.core.plan import KernelPlan
 from repro.graph.generators import ring_graph, rmat_edges
 from repro.graph.structures import EdgeDelta
 from repro.kernels.segment_combine import BLOCK_V
@@ -188,7 +188,7 @@ def test_dense_combine_route_resolution(graph, case):
     assert resolve_combine_route(program, part, nseg) == want
     eng = GREEngine(program)
     assert eng.combine_route(part, nseg) == want
-    assert eng.make_plan().kernel == XLA_KERNEL
+    assert eng.make_plan().kernel == KernelPlan(use_pallas=None)
     assert GREEngine(program, use_pallas=True).combine_route(
         part, nseg) == "pallas"
     assert GREEngine(program, use_pallas=False).combine_route(

@@ -8,7 +8,9 @@ Nothing runs.  The topology is described inside a fixture, never at import
 time (only one process at a time may load the TPU library).
 """
 import dataclasses
+import difflib
 import re
+from pathlib import Path
 
 import numpy as np
 import pytest
@@ -421,3 +423,70 @@ def test_agent_shards_combine_on_the_kernel_without_edge_copies(topo,
         # of an edge column would add a whole tile's column
         assert temp["default"] - temp["xla"] < 4 * min(e for e, _ in
                                                       combines)
+
+
+# ---------------------------------------------- the compiled runs, as text
+LOWERED = Path(__file__).resolve().parent / "data" / "lowered"
+
+
+def lowered_runs(topo) -> dict:
+    """name -> StableHLO text of the engines' compiled runs, lowered for the
+    described chip with no source locations: `GREEngine.run` for PageRank
+    and CC on a scale-8 R-MAT partition, and `DistGREEngine.make_run`
+    (agent exchange, PageRank) on a 1-device mesh.  Only public calls, so
+    the same function lowers any tree of the engine."""
+    one = SingleDeviceSharding(topo.devices[0])
+    g = rmat_edges(scale=8, edge_factor=8, seed=1, weights=True).dedup()
+    # no traceback frames in locations, so the Pallas kernel's serialized
+    # body carries no source paths or line numbers either
+    limit = jax.config.jax_traceback_in_locations_limit
+    jax.config.update("jax_traceback_in_locations_limit", 0)
+    try:
+        texts = {}
+        for name, graph in (("pagerank", g),
+                            ("cc", g.as_undirected().dedup())):
+            part = DevicePartition.from_graph(graph)
+            eng = GREEngine(getattr(algorithms, f"{name}_program")())
+            state = eng.init_state(part)
+            texts[f"run-{name}"] = GREEngine.run.lower(
+                eng, _abstract(part, one), _abstract(state, one),
+                30).as_text()
+        prog = algorithms.pagerank_program()
+        ag = build_agent_graph(g, hash_partition(g, 1), 1)
+        host = DistGREEngine(prog, jax.make_mesh((1,), ("graph",)))
+        mesh = jax.sharding.Mesh(np.asarray(topo.devices[:1]), ("graph",))
+        rows = NamedSharding(mesh, P("graph"))
+        eng = DistGREEngine(prog, mesh, ("graph",), exchange="agent")
+        texts["make_run-agent"] = eng.make_run(ag, max_steps=30).lower(
+            _abstract(host.device_topology(ag), rows),
+            _abstract(host.init_state(ag), rows)).as_text()
+    finally:
+        jax.config.update("jax_traceback_in_locations_limit", limit)
+    return {name: "\n".join(line for line in text.splitlines()
+                            if not line.startswith("#loc")) + "\n"
+            for name, text in texts.items()}
+
+
+@pytest.mark.parametrize("name", ["run-pagerank", "run-cc", "make_run-agent"])
+def test_compiled_runs_match_reference_text(topo, name):
+    """The engines' compiled runs lower to the StableHLO kept in
+    `tests/data/lowered/` (made by `python tests/test_tpu_compile.py`):
+    a change to the state lifecycle around the run leaves the program that
+    runs on the chip as it was."""
+    got = lowered_runs(topo)[name]
+    want = (LOWERED / f"{name}.mlir").read_text()
+    if got != want:
+        diff = difflib.unified_diff(want.splitlines(), got.splitlines(),
+                                    "reference", "lowered", lineterm="")
+        pytest.fail("\n".join(line[:200] for line in list(diff)[:40]))
+
+
+if __name__ == "__main__":
+    # (re)write the reference text with the `repro` on PYTHONPATH
+    from jax.experimental import topologies
+    jax.config.update("jax_enable_compilation_cache", False)
+    LOWERED.mkdir(parents=True, exist_ok=True)
+    for name, text in lowered_runs(topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")).items():
+        (LOWERED / f"{name}.mlir").write_text(text)
+        print(name, len(text))
